@@ -21,6 +21,7 @@ import numpy as np
 from repro.configs import ASSIGNED_ARCHS, get_config, reduced
 from repro.configs.base import RLConfig, RuntimeConfig
 from repro.envs.toy_manipulation import SUITES, lognormal_latency
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import AcceRLSystem
 
 
@@ -54,6 +55,7 @@ def main() -> None:
                     choices=("drop_oldest", "drop_newest", "block"),
                     help="experience-channel policy when B is full")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from common import bc_train, collect_demos, eval_policy  # benchmarks/
 
@@ -78,7 +80,8 @@ def main() -> None:
     mode = "SYNC baseline" if args.sync else "ASYNC AcceRL"
     print(f"[2/3] {mode}: {args.steps} trainer steps, "
           f"{args.workers} rollout workers")
-    # same services either way — only the scheduler differs
+    # same services either way — only the scheduler differs; a crashed
+    # service raises ServiceFailure here (non-zero exit with its traceback)
     runner = sys_.run_sync if args.sync else sys_.run_async
     m = runner(train_steps=args.steps,
                wall_timeout_s=args.wall_minutes * 60)
@@ -88,7 +91,7 @@ def main() -> None:
           f"rollout success {m['success_rate']:.2f}")
     unhealthy = {k: h for k, h in sys_.health().items() if not h["healthy"]}
     if unhealthy:
-        print(f"      WARNING unhealthy services: {unhealthy}")
+        sys.exit(f"unhealthy services: {unhealthy}")
 
     print("[3/3] final evaluation")
     final = sys_.evaluate(episodes=20)

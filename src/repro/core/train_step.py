@@ -57,22 +57,36 @@ def init_train_state(cfg: ModelConfig, key, *, mesh=None) -> TrainState:
     """
     from repro.models.policy import init_policy_params
     params = init_policy_params(cfg, key)
-    opt = adamw.init(params)
+    state = TrainState(params=params, opt=adamw.init(params),
+                       adv_norm=advnorm.init_adv_state(),
+                       version=jnp.zeros((), jnp.int32))
     if mesh is not None and getattr(mesh, "devices", None) is not None \
             and mesh.devices.size > 1:
-        from repro.sharding import rules
-        shapes = jax.tree.map(
-            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), params)
-        pspec = rules.param_specs(cfg, shapes, mesh)
-        from jax.sharding import NamedSharding
-        params = jax.device_put(
-            params, jax.tree.map(lambda s: NamedSharding(mesh, s), pspec,
-                                 is_leaf=lambda x: not isinstance(x, dict)))
-        from repro.optim import zero
-        opt = zero.shard_opt_state(opt, mesh, param_specs=pspec)
-    return TrainState(params=params, opt=opt,
-                      adv_norm=advnorm.init_adv_state(),
-                      version=jnp.zeros((), jnp.int32))
+        state = jax.device_put(state, state_shardings(cfg, mesh))
+    return state
+
+
+def state_shardings(cfg: ModelConfig, mesh) -> TrainState:
+    """NamedSharding tree of the live TrainState on ``mesh``: params under
+    the TP/FSDP rules, f32 Adam moments additionally ZeRO-sharded over
+    ``data`` (``optim.zero``), the scalars replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.models.policy import init_policy_params
+    from repro.optim import zero
+    from repro.sharding import rules
+    shapes = jax.eval_shape(functools.partial(init_policy_params, cfg),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    pspec = rules.param_specs(cfg, shapes, mesh)
+    moments = zero.moment_shardings(shapes, mesh, param_specs=pspec)
+    rep = NamedSharding(mesh, P())
+    return TrainState(
+        params=jax.tree.map(lambda s: NamedSharding(mesh, s), pspec,
+                            is_leaf=lambda x: isinstance(x, P)),
+        opt=adamw.AdamWState(step=rep, mu=moments, nu=moments),
+        adv_norm=jax.tree.map(lambda _: rep,
+                              jax.eval_shape(advnorm.init_adv_state)),
+        version=rep)
 
 
 def _score_batch(cfg: ModelConfig, params, micro: TrajectoryBatch, *,

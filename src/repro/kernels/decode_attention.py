@@ -8,18 +8,21 @@ the sequence (cache rows written by longer sequences in the batch) — so
 the mask arrives as a precomputed additive bias instead of being derived
 from grid positions:
 
-  * grid = (batch, q-heads, kv-blocks); the LAST axis is sequential on
+  * grid = (batch, kv-heads, kv-blocks); the LAST axis is sequential on
     TPU, so the online-softmax state (m, l, acc) lives in VMEM scratch
-    across kv-block steps and is finalized on the last step (same shape
-    as ``flash_attention``, with a 1-row query tile);
-  * GQA maps each q-head grid index to its kv head (h // group) in the
-    K/V index maps — no KV duplication in HBM;
+    across kv-block steps and is finalized on the last step (the same
+    update as ``flash_attention``);
+  * one grid step serves the whole GQA group of a kv head: the query
+    block is ``[group, D]`` — a block whose minor axes equal the array's
+    own, which the TPU compiler accepts for any group size (MHA: 1);
+  * K/V are read heads-major (``[B, KV, S, D]``), so every block's minor
+    axes are ``(block_k, head_dim)``;
   * ``bias``: [B, S] f32, 0 where the cache slot is attendable and
     ``NEG_INF`` where it is not; cache padding to the block multiple is
-    masked the same way.
+    masked the same way. It enters the kernel as ``[B, 1, S]`` rows.
 
-Validated in interpret mode against the dense jnp decode path; on real
-TPUs the same ``pl.pallas_call`` lowers to Mosaic.
+Validated in interpret mode against the dense jnp decode path and
+compiled for a described TPU v5e in ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -30,7 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.flash_attention import _vmem
+from repro.kernels.flash_attention import (_NT, _heads_major, _lanes,
+                                           _softmax_scratch, _softmax_step)
 
 NEG_INF = -1e30
 
@@ -46,27 +50,15 @@ def _decode_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :]                              # [1, D]
-    k = k_ref[0, :, 0, :]                              # [bk, D]
-    v = v_ref[0, :, 0, :]                              # [bk, D]
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    s = s + bias_ref[...]                              # [1, bk]
-
-    m_prev = m_scr[...]                                # [1, 1]
-    m_new = jnp.maximum(m_prev[:, 0], s.max(axis=-1))[:, None]
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                             # [1, bk]
-    l_new = l_scr[...] * corr + p.sum(axis=-1)[:, None]
-    acc_scr[...] = acc_scr[...] * corr + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
-    l_scr[...] = l_new
+    s = jax.lax.dot_general(q_ref[...], k_ref[...], _NT,
+                            preferred_element_type=jnp.float32) * scale
+    _softmax_step(s + bias_ref[...], v_ref[...], m_scr, l_scr, acc_scr)
 
     @pl.when(kj == nk - 1)
     def _final():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / _lanes(denom, acc_scr.shape[1])
+                      ).astype(o_ref.dtype)
 
 
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -82,33 +74,25 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     scale = d ** -0.5
 
     sp = math.ceil(s / block_k) * block_k
-    if sp != s:
-        k = jnp.pad(k, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
-        bias = jnp.pad(bias, ((0, 0), (0, sp - s)),
-                       constant_values=NEG_INF)
-    bias = bias.astype(jnp.float32)
+    kh = _heads_major(k, sp - s)
+    vh = _heads_major(v, sp - s)
+    bias = jnp.pad(bias.astype(jnp.float32), ((0, 0), (0, sp - s)),
+                   constant_values=NEG_INF)[:, None, :]
+    qg = q.reshape(b, kv, group, d)          # head h = kv_head * group + g
 
-    grid = (b, h, sp // block_k)
+    q_spec = pl.BlockSpec((None, None, group, d),
+                          lambda bi, gi, kj: (bi, gi, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, d),
+                           lambda bi, gi, kj: (bi, gi, kj, 0))
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, d), lambda bi, hi, kj: (bi, 0, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda bi, hi, kj, g=group: (bi, kj, hi // g, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda bi, hi, kj, g=group: (bi, kj, hi // g, 0)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, kj: (bi, kj)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, d),
-                               lambda bi, hi, kj: (bi, 0, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
-        scratch_shapes=[
-            _vmem((1, 1), jnp.float32),        # running max m
-            _vmem((1, 1), jnp.float32),        # running sum l
-            _vmem((1, d), jnp.float32),        # accumulator
-        ],
+        grid=(b, kv, sp // block_k),
+        in_specs=[q_spec, kv_spec, kv_spec,
+                  pl.BlockSpec((None, 1, block_k),
+                               lambda bi, gi, kj: (bi, 0, kj))],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kv, group, d), q.dtype),
+        scratch_shapes=_softmax_scratch(group, d),
         interpret=interpret,
-    )(q, k, v, bias)
-    return out
+    )(qg, kh, vh, bias)
+    return out.reshape(b, 1, h, d)

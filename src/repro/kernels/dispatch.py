@@ -28,6 +28,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import gipo_loss as _gl
 from repro.kernels.flash_attention import flash_attention
@@ -88,6 +89,21 @@ def interpret_mode() -> bool:
     return not _on_tpu()
 
 
+def _per_device(fn, *args):
+    """Call a Pallas route once per device of the enclosing mesh.
+
+    The TPU compiler cannot partition a Mosaic kernel across devices, so
+    under a multi-device mesh (``jax.set_mesh``, as the data-parallel
+    trainer traces its step) the call runs inside ``shard_map`` on
+    replicated operands: every device computes the whole op, as it does
+    the rest of that trainer's replicated-batch step."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return fn(*args)
+    return jax.shard_map(fn, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
+
+
 # ---------------------------------------------------------------------------
 # Streaming jnp twins (share the block math with the Pallas kernels)
 # ---------------------------------------------------------------------------
@@ -112,10 +128,11 @@ def _scan_blocks(body, operands, block_n: int):
 def _jnp_gipo_loss(logits, targets, logp_old, advantages, mask, sigma,
                    block_n):
     def body(lg, tg, lo, ad, mk):
-        return _gl._fwd_partials(lg.astype(jnp.float32), tg, lo, ad, mk,
-                                 sigma, sg=jax.lax.stop_gradient)
-    sums = _scan_blocks(body, (logits, targets, logp_old, advantages, mask),
-                        block_n)
+        return _gl._partials_vector(_gl._fwd_partials(
+            lg.astype(jnp.float32), tg, lo, ad, mk, sigma,
+            sg=jax.lax.stop_gradient))
+    sums = _scan_blocks(body, (logits, *_gl._columns(
+        targets, logp_old, advantages, mask)), block_n)
     return _gl._finalize(sums)
 
 
@@ -123,10 +140,10 @@ def _jnp_policy_loss(hidden, w, targets, logp_old, advantages, mask, sigma,
                      block_n):
     def body(h, tg, lo, ad, mk):
         logits = jnp.dot(h, w, preferred_element_type=jnp.float32)
-        return _gl._fwd_partials(logits, tg, lo, ad, mk, sigma,
-                                 sg=jax.lax.stop_gradient)
-    sums = _scan_blocks(body, (hidden, targets, logp_old, advantages, mask),
-                        block_n)
+        return _gl._partials_vector(_gl._fwd_partials(
+            logits, tg, lo, ad, mk, sigma, sg=jax.lax.stop_gradient))
+    sums = _scan_blocks(body, (hidden, *_gl._columns(
+        targets, logp_old, advantages, mask)), block_n)
     return _gl._finalize(sums)
 
 
@@ -147,8 +164,10 @@ def gipo_loss(logits, targets, logp_old, advantages, mask, *, sigma: float,
     """Logits-level fused GIPO/entropy/KL -> (pg, entropy, kl, metrics)."""
     block_n = block_n or loss_block_n(mode)
     if use_pallas(mode):
-        return _gl.gipo_head_loss(logits, targets, logp_old, advantages,
-                                  mask, sigma, block_n, interpret_mode())
+        interpret = interpret_mode()
+        return _per_device(
+            lambda *a: _gl.gipo_head_loss(*a, sigma, block_n, interpret),
+            logits, targets, logp_old, advantages, mask)
     return _jnp_gipo_loss(logits, targets, logp_old, advantages, mask,
                           sigma, block_n)
 
@@ -164,9 +183,10 @@ def policy_head_loss(hidden, w, targets, logp_old, advantages, mask, *,
     """
     block_n = block_n or loss_block_n(mode)
     if use_pallas(mode):
-        return _gl.fused_policy_loss(hidden, w, targets, logp_old,
-                                     advantages, mask, sigma, block_n,
-                                     interpret_mode())
+        interpret = interpret_mode()
+        return _per_device(
+            lambda *a: _gl.fused_policy_loss(*a, sigma, block_n, interpret),
+            hidden, w, targets, logp_old, advantages, mask)
     return _jnp_policy_loss(hidden, w, targets, logp_old, advantages, mask,
                             sigma, block_n)
 
@@ -223,6 +243,12 @@ _flash_with_twin_bwd.defvjp(_flash_fwd, _flash_bwd)
 # SSD scan (Mamba2): Pallas chunked forward + Pallas reverse-sweep backward
 # ---------------------------------------------------------------------------
 
+def _ssd_pallas_ok(chunk: int) -> bool:
+    """On a real TPU the kernel's dt rows are ``(1, chunk)`` blocks, which
+    want whole 128-wide lane tiles. Interpret mode takes any chunk."""
+    return interpret_mode() or chunk % 128 == 0
+
+
 def _twin_ssd(x, dt, A, Bm, Cm, chunk):
     from repro.models.ssm import ssd_chunked
     return ssd_chunked(x, dt, A, Bm, Cm, chunk)
@@ -264,20 +290,32 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128,
     Returns (y [B,T,H,P] f32, final_state [B,H,P,N] f32).
 
     Routes to the Pallas kernel when enabled and shape-eligible (the
-    kernel wants T an exact multiple of ``chunk``; ragged lengths and
-    decode-time carried state stay on the jnp path). Backward on the
+    kernel wants T an exact multiple of ``chunk``, and on TPU a chunk of
+    whole lane tiles; ragged lengths and decode-time carried state stay
+    on the jnp path). Backward on the
     Pallas route is the reverse-chunk Pallas kernel replaying saved
     entering states (``ssd_scan_bwd``); the jnp path uses its own VJP.
     """
     t = x.shape[1]
-    if use_pallas(mode) and t >= chunk and t % chunk == 0:
-        return _ssd_with_twin_bwd(x, dt, A, Bm, Cm, chunk, interpret_mode())
+    if (use_pallas(mode) and t >= chunk and t % chunk == 0
+            and _ssd_pallas_ok(chunk)):
+        interpret = interpret_mode()
+        return _per_device(
+            lambda *a: _ssd_with_twin_bwd(*a, chunk, interpret),
+            x, dt, A, Bm, Cm)
     return _twin_ssd(x, dt, A, Bm, Cm, chunk)
 
 
 # ---------------------------------------------------------------------------
 # Attention routing
 # ---------------------------------------------------------------------------
+
+def _flash(q, k, v, window, block):
+    interpret = interpret_mode()
+    return _per_device(
+        lambda *a: _flash_with_twin_bwd(*a, window, block, block, interpret),
+        q, k, v)
+
 
 def attention(q, k, v, *, window: Optional[int] = None, block: int = 128,
               unroll: bool = False, mode: Optional[str] = None):
@@ -290,8 +328,7 @@ def attention(q, k, v, *, window: Optional[int] = None, block: int = 128,
     either way). Otherwise the jnp twin runs both ways.
     """
     if use_pallas(mode) and _attn_pallas_ok(q.shape[-1]):
-        return _flash_with_twin_bwd(q, k, v, window, block, block,
-                                    interpret_mode())
+        return _flash(q, k, v, window, block)
     return _twin_attention(q, k, v, window, block, unroll)
 
 
@@ -324,8 +361,7 @@ def dense_attention(q, k, v, *, window: Optional[int] = None,
     differentiable through the twin-VJP wrapper like ``attention``.
     """
     if use_pallas(mode) and _attn_pallas_ok(q.shape[-1]):
-        return _flash_with_twin_bwd(q, k, v, window, block, block,
-                                    interpret_mode())
+        return _flash(q, k, v, window, block)
     return _twin_dense(q, k, v, window)
 
 
@@ -355,5 +391,8 @@ def decode_attention(q, k, v, valid, *, mode: Optional[str] = None):
             decode_attention as _pallas_decode)
         from repro.models.attention import NEG_INF
         bias = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)
-        return _pallas_decode(q, k, v, bias, interpret=interpret_mode())
+        interpret = interpret_mode()
+        return _per_device(
+            lambda *a: _pallas_decode(*a, interpret=interpret),
+            q, k, v, bias)
     return _twin_decode(q, k, v, valid)

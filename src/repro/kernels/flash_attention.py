@@ -4,12 +4,17 @@ Inference-worker prefill/decode dominates rollout latency (paper §3.2) —
 this is the hot spot the framework optimizes. TPU adaptation of the
 flash-attention algorithm:
 
+  * the wrapper moves heads ahead of the sequence (``[B, H, T, D]``), so
+    every block's two minor axes are ``(block, head_dim)`` — the
+    (sublane, lane) tile the TPU compiler requires, with head_dim on the
+    128-wide lanes;
   * grid = (batch, q-heads, q-blocks, kv-blocks); the LAST grid axis is
     iterated sequentially on TPU ("arbitrary" dimension semantics), so the
     online-softmax state (m, l, acc) lives in VMEM scratch across kv-block
     steps and is finalized on the last step;
-  * BlockSpecs tile Q/K/V into MXU-aligned [block, head_dim] tiles resident
-    in VMEM; ``head_dim`` and the default blocks are multiples of 128;
+  * per-row statistics (running max, running sum, the saved LSE and the
+    backward's D = rowsum(dO∘O)) are kept lane-replicated as
+    ``[rows, 128]`` tiles — a lone ``[rows]`` vector has no TPU tile;
   * GQA is handled by mapping each q-head grid index to its kv head
     (h // group) in the K/V index maps — no KV duplication in HBM;
   * causal + sliding-window masking from absolute positions.
@@ -21,8 +26,8 @@ the forward optionally saves the per-row log-sum-exp (``return_lse``), and
 over kv blocks in one kernel and dk/dv over q blocks in the other. GQA
 dk/dv come out per q-head and are summed over the group outside.
 
-Validated in interpret mode against ``ref.reference_attention`` (CPU); on
-real TPUs the same ``pl.pallas_call`` lowers to Mosaic.
+Validated in interpret mode against ``ref.reference_attention`` (CPU) and
+compiled for a described TPU v5e in ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -33,8 +38,50 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128                    # width of a lane-replicated per-row tile
+_NT = (((1,), (1,)), ((), ()))  # dot_general dims for a @ b.T
+
+
+def _lanes(x: jnp.ndarray, n: int) -> jnp.ndarray:
+    """Widen a lane-replicated ``[rows, LANES]`` tile to ``[rows, n]``."""
+    if n % LANES == 0:
+        return jnp.tile(x, (1, n // LANES))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))   # interpret-only
+
+
+def _softmax_step(s, v, m_scr, l_scr, acc_scr):
+    """One online-softmax update of (m, l, acc) with a [rows, bk] score
+    block ``s`` (already masked) and its [bk, D] values ``v``."""
+    m_prev = m_scr[...]                                # [rows, LANES]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    corr = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - _lanes(m_new, s.shape[1]))         # [rows, bk]
+    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * _lanes(corr, acc_scr.shape[1]) + jnp.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
+
+
+def _softmax_scratch(rows: int, d: int):
+    return [pltpu.VMEM((rows, LANES), jnp.float32),    # running max m
+            pltpu.VMEM((rows, LANES), jnp.float32),    # running sum l
+            pltpu.VMEM((rows, d), jnp.float32)]        # accumulator
+
+
+def _mask(qi, kj, block_q, block_k, seq_k, causal, window):
+    """[block_q, block_k] validity from absolute positions."""
+    shape = (block_q, block_k)
+    qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    kpos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    mask = kpos < seq_k
+    if causal:
+        mask &= kpos <= qpos
+    if window is not None:
+        mask &= (qpos - kpos) < window
+    return mask
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
@@ -44,7 +91,6 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
         lse_ref, m_scr, l_scr, acc_scr = refs
     else:
         m_scr, l_scr, acc_scr = refs
-        lse_ref = None
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -55,41 +101,29 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :]                              # [bq, D]
-    k = k_ref[0, :, 0, :]                              # [bk, D]
-    v = v_ref[0, :, 0, :]                              # [bk, D]
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-
-    qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32,
-                                                   (block_q, block_k), 0)
-    kpos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                   (block_q, block_k), 1)
-    mask = kpos < seq_k
-    if causal:
-        mask &= kpos <= qpos
-    if window is not None:
-        mask &= (qpos - kpos) < window
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_scr[...]                                # [bq, 1]
-    m_new = jnp.maximum(m_prev[:, 0], s.max(axis=-1))[:, None]
-    corr = jnp.exp(m_prev - m_new)                     # [bq, 1]
-    p = jnp.exp(s - m_new)                             # [bq, bk]
-    l_new = l_scr[...] * corr + p.sum(axis=-1)[:, None]
-    acc_scr[...] = acc_scr[...] * corr + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
-    l_scr[...] = l_new
+    s = jax.lax.dot_general(q_ref[...], k_ref[...], _NT,
+                            preferred_element_type=jnp.float32) * scale
+    mask = _mask(qi, kj, block_q, block_k, seq_k, causal, window)
+    _softmax_step(jnp.where(mask, s, NEG_INF), v_ref[...], m_scr, l_scr,
+                  acc_scr)
 
     @pl.when(kj == nk - 1)
     def _final():
-        denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_scr[...], 1e-30)         # [bq, LANES]
+        o_ref[...] = (acc_scr[...] / _lanes(denom, acc_scr.shape[1])
+                      ).astype(o_ref.dtype)
         if save_lse:
             # per-row log-sum-exp: the softmax residual the backward
             # kernels replay p = exp(s - LSE) from (no second pass)
-            lse_ref[0, :, 0] = m_scr[...][:, 0] + jnp.log(denom[:, 0])
+            lse_ref[...] = m_scr[...] + jnp.log(denom)
+
+
+def _heads_major(x, seq_pad: int):
+    """[B, T, H, D] -> [B, H, T + seq_pad, D]."""
+    x = jnp.swapaxes(x, 1, 2)
+    if seq_pad:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, seq_pad), (0, 0)))
+    return x
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -101,7 +135,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     T and S are padded to block multiples internally; the causal mask uses
     unpadded absolute positions, and key padding is masked out.
     ``return_lse`` additionally returns the per-row log-sum-exp
-    [B, T, H] f32 — the residual ``flash_attention_bwd`` needs.
+    [B, H, T] f32 — the residual ``flash_attention_bwd`` needs.
     """
     b, t, h, d = q.shape
     s, kv = k.shape[1], k.shape[2]
@@ -111,73 +145,60 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     tp = math.ceil(t / block_q) * block_q
     sp = math.ceil(s / block_k) * block_k
-    if tp != t:
-        q = jnp.pad(q, ((0, 0), (0, tp - t), (0, 0), (0, 0)))
-    if sp != s:
-        k = jnp.pad(k, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
+    qh = _heads_major(q, tp - t)
+    kh = _heads_major(k, sp - s)
+    vh = _heads_major(v, sp - s)
 
-    grid = (b, h, tp // block_q, sp // block_k)
     kernel = functools.partial(
         _attn_kernel, scale=scale, block_q=block_q, block_k=block_k,
         seq_k=s, causal=causal, window=window, save_lse=return_lse)
-    out_specs = [pl.BlockSpec((1, block_q, 1, d),
-                              lambda bi, hi, qi, kj: (bi, qi, hi, 0))]
-    out_shape = [jax.ShapeDtypeStruct((b, tp, h, d), q.dtype)]
+    q_spec = pl.BlockSpec((None, None, block_q, d),
+                          lambda bi, hi, qi, kj: (bi, hi, qi, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, d),
+                           lambda bi, hi, qi, kj: (bi, hi // group, kj, 0))
+    out_specs = [q_spec]
+    out_shape = [jax.ShapeDtypeStruct((b, h, tp, d), q.dtype)]
     if return_lse:
-        out_specs.append(pl.BlockSpec((1, block_q, 1),
-                                      lambda bi, hi, qi, kj: (bi, qi, hi)))
-        out_shape.append(jax.ShapeDtypeStruct((b, tp, h), jnp.float32))
+        out_specs.append(pl.BlockSpec(
+            (None, None, block_q, LANES),
+            lambda bi, hi, qi, kj: (bi, hi, qi, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b, h, tp, LANES),
+                                              jnp.float32))
     got = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda bi, hi, qi, kj: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda bi, hi, qi, kj, g=group: (bi, kj, hi // g, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda bi, hi, qi, kj, g=group: (bi, kj, hi // g, 0)),
-        ],
-        out_specs=out_specs if return_lse else out_specs[0],
-        out_shape=out_shape if return_lse else out_shape[0],
-        scratch_shapes=[
-            _vmem((block_q, 1), jnp.float32),      # running max m
-            _vmem((block_q, 1), jnp.float32),      # running sum l
-            _vmem((block_q, d), jnp.float32),      # accumulator
-        ],
+        grid=(b, h, tp // block_q, sp // block_k),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=_softmax_scratch(block_q, d),
         interpret=interpret,
-    )(q, k, v)
+    )(qh, kh, vh)
+    out = jnp.swapaxes(got[0][:, :, :t], 1, 2)
     if return_lse:
-        out, lse = got
-        return out[:, :t], lse[:, :t]
-    return got[:, :t]
+        return out, got[1][:, :, :t, 0]
+    return out
 
 
 # ---------------------------------------------------------------------------
 # Backward: two Pallas kernels replaying the online softmax from the LSE
 # ---------------------------------------------------------------------------
 
-def _bwd_mask(qi, kj, block_q, block_k, seq_k, causal, window, transposed):
-    """Same absolute-position mask as the forward; ``transposed`` gives it
-    in [block_k, block_q] layout for the dk/dv kernel."""
-    shape = (block_k, block_q) if transposed else (block_q, block_k)
-    qax, kax = (1, 0) if transposed else (0, 1)
-    qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, qax)
-    kpos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, kax)
-    mask = kpos < seq_k
-    if causal:
-        mask &= kpos <= qpos
-    if window is not None:
-        mask &= (qpos - kpos) < window
-    return mask
+def _replay(q, k, do, v, lse, dd, mask, scale):
+    """Softmax probabilities and score cotangents of one [bq, bk] block:
+    p = exp(s − LSE); ds = p ∘ (dO·Vᵀ − D)."""
+    bk = k.shape[0]
+    s = jax.lax.dot_general(q, k, _NT,
+                            preferred_element_type=jnp.float32) * scale
+    p = jnp.where(mask, jnp.exp(s - _lanes(lse, bk)), 0.0)
+    dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
+    return p, p * (dp - _lanes(dd, bk))
 
 
 def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                         dq_ref, dq_scr, *, scale, block_q, block_k, seq_k,
                         causal, window):
     """dq accumulated over kv blocks (last grid axis sequential):
-    p = exp(s - LSE); ds = p ∘ (dO·Vᵀ − D); dq += ds·K·scale."""
+    dq += ds·K·scale."""
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -186,32 +207,25 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    do = do_ref[0, :, 0, :].astype(jnp.float32)
-    lse = lse_ref[0, :, 0]                                 # [bq]
-    dd = dd_ref[0, :, 0]                                   # [bq] rowsum(dO∘O)
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    mask = _bwd_mask(qi, kj, block_q, block_k, seq_k, causal, window, False)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)    # [bq, bk]
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - dd[:, None])
-    dq_scr[...] += jnp.dot(ds, k,
-                           preferred_element_type=jnp.float32) * scale
+    k = k_ref[...].astype(jnp.float32)
+    mask = _mask(qi, kj, block_q, block_k, seq_k, causal, window)
+    _, ds = _replay(q_ref[...].astype(jnp.float32), k,
+                    do_ref[...].astype(jnp.float32),
+                    v_ref[...].astype(jnp.float32), lse_ref[...],
+                    dd_ref[...], mask, scale)
+    dq_scr[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32) * scale
 
     @pl.when(kj == nk - 1)
     def _final():
-        dq_ref[0, :, 0, :] = dq_scr[...]
+        dq_ref[...] = dq_scr[...]
 
 
 def _attn_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dd_ref,
                          dk_ref, dv_ref, dk_scr, dv_scr, *, scale, block_q,
                          block_k, seq_k, causal, window):
     """dk/dv for one k-block accumulated over q blocks (last grid axis):
-    dv += pᵀ·dO; dk += (p ∘ (V·dOᵀ − D))ᵀ-form·Q·scale. Emitted per
-    q-head; the wrapper sums heads over each GQA group."""
+    dv += pᵀ·dO; dk += dsᵀ·Q·scale. Emitted per q-head; the wrapper sums
+    heads over each GQA group."""
     ki = pl.program_id(2)
     qj = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -221,26 +235,20 @@ def _attn_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dd_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    q = q_ref[0, :, 0, :].astype(jnp.float32)
-    do = do_ref[0, :, 0, :].astype(jnp.float32)
-    lse = lse_ref[0, :, 0]                                 # [bq]
-    dd = dd_ref[0, :, 0]                                   # [bq]
-
-    st = jnp.dot(k, q.T, preferred_element_type=jnp.float32) * scale
-    mask = _bwd_mask(qj, ki, block_q, block_k, seq_k, causal, window, True)
-    pt = jnp.where(mask, jnp.exp(st - lse[None, :]), 0.0)  # [bk, bq]
-    dv_scr[...] += jnp.dot(pt, do, preferred_element_type=jnp.float32)
-    dpt = jnp.dot(v, do.T, preferred_element_type=jnp.float32)
-    dst = pt * (dpt - dd[None, :])
-    dk_scr[...] += jnp.dot(dst, q,
+    q = q_ref[...].astype(jnp.float32)
+    do = do_ref[...].astype(jnp.float32)
+    mask = _mask(qj, ki, block_q, block_k, seq_k, causal, window)
+    p, ds = _replay(q, k_ref[...].astype(jnp.float32), do,
+                    v_ref[...].astype(jnp.float32), lse_ref[...],
+                    dd_ref[...], mask, scale)
+    dv_scr[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
+    dk_scr[...] += jnp.dot(ds.T, q,
                            preferred_element_type=jnp.float32) * scale
 
     @pl.when(qj == nq - 1)
     def _final():
-        dk_ref[0, :, 0, :] = dk_scr[...]
-        dv_ref[0, :, 0, :] = dv_scr[...]
+        dk_ref[...] = dk_scr[...]
+        dv_ref[...] = dv_scr[...]
 
 
 # padded q rows carry dO = 0 and D = 0, so their p·(…) products vanish;
@@ -249,12 +257,18 @@ def _attn_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dd_ref,
 _LSE_PAD = 1e30
 
 
+def _row_stat(x, seq_pad: int, pad_value: float = 0.0):
+    """[B, H, T] f32 -> lane-replicated [B, H, T + seq_pad, LANES]."""
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, seq_pad)), constant_values=pad_value)
+    return jnp.broadcast_to(x[..., None], x.shape + (LANES,))
+
+
 def flash_attention_bwd(q, k, v, out, lse, do, *, causal: bool = True,
                         window: Optional[int] = None, block_q: int = 128,
                         block_k: int = 128, interpret: bool = False):
     """Gradients (dq, dk, dv) from the saved forward residuals.
 
-    q: [B,T,H,D]; k/v: [B,S,KV,D]; out/do: like q; lse: [B,T,H] f32 from
+    q: [B,T,H,D]; k/v: [B,S,KV,D]; out/do: like q; lse: [B,H,T] f32 from
     ``flash_attention(..., return_lse=True)``. Recompute-free: the online
     softmax is replayed as ``p = exp(s − LSE)`` — one pass per kernel.
     """
@@ -267,79 +281,57 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal: bool = True,
 
     tp = math.ceil(t / block_q) * block_q
     sp = math.ceil(s / block_k) * block_k
-    if tp != t:
-        pad4 = ((0, 0), (0, tp - t), (0, 0), (0, 0))
-        q = jnp.pad(q, pad4)
-        do = jnp.pad(do, pad4)
-        lse = jnp.pad(lse, ((0, 0), (0, tp - t), (0, 0)),
-                      constant_values=_LSE_PAD)
-        dd = jnp.pad(dd, ((0, 0), (0, tp - t), (0, 0)))
-    if sp != s:
-        pad4 = ((0, 0), (0, sp - s), (0, 0), (0, 0))
-        k = jnp.pad(k, pad4)
-        v = jnp.pad(v, pad4)
+    qh, doh = _heads_major(q, tp - t), _heads_major(do, tp - t)
+    kh, vh = _heads_major(k, sp - s), _heads_major(v, sp - s)
+    lse_l = _row_stat(lse, tp - t, _LSE_PAD)
+    dd_l = _row_stat(jnp.swapaxes(dd, 1, 2), tp - t)
 
-    # index-map helpers: in the dq kernel the q-block index is grid axis 2
-    # and the kv-block axis 3; the dkv kernel swaps them
-    kq_spec = lambda qax: pl.BlockSpec(
-        (1, block_q, 1, d),
-        (lambda bi, hi, i, j: (bi, i, hi, 0)) if qax == 2 else
-        (lambda bi, hi, i, j: (bi, j, hi, 0)))
-    kk_spec = lambda kax: pl.BlockSpec(
-        (1, block_k, 1, d),
-        (lambda bi, hi, i, j, g=group: (bi, j, hi // g, 0)) if kax == 3 else
-        (lambda bi, hi, i, j, g=group: (bi, i, hi // g, 0)))
-    row_spec = lambda qax: pl.BlockSpec(
-        (1, block_q, 1),
-        (lambda bi, hi, i, j: (bi, i, hi)) if qax == 2 else
-        (lambda bi, hi, i, j: (bi, j, hi)))
+    # index maps: in the dq kernel the q-block index is grid axis 2 and
+    # the kv-block axis 3; the dkv kernel swaps them
+    def q_spec(width, qax):
+        return pl.BlockSpec(
+            (None, None, block_q, width),
+            (lambda bi, hi, i, j: (bi, hi, i, 0)) if qax == 2 else
+            (lambda bi, hi, i, j: (bi, hi, j, 0)))
+
+    def k_spec(kax):
+        return pl.BlockSpec(
+            (None, None, block_k, d),
+            (lambda bi, hi, i, j: (bi, hi // group, j, 0)) if kax == 3 else
+            (lambda bi, hi, i, j: (bi, hi // group, i, 0)))
 
     kernel_kw = dict(scale=scale, block_q=block_q, block_k=block_k,
                      seq_k=s, causal=causal, window=window)
     dq = pl.pallas_call(
         functools.partial(_attn_bwd_dq_kernel, **kernel_kw),
         grid=(b, h, tp // block_q, sp // block_k),
-        in_specs=[kq_spec(2), kk_spec(3), kk_spec(3), kq_spec(2),
-                  row_spec(2), row_spec(2)],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda bi, hi, i, j: (bi, i, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, tp, h, d), jnp.float32),
-        scratch_shapes=[_vmem((block_q, d), jnp.float32)],
+        in_specs=[q_spec(d, 2), k_spec(3), k_spec(3), q_spec(d, 2),
+                  q_spec(LANES, 2), q_spec(LANES, 2)],
+        out_specs=q_spec(d, 2),
+        out_shape=jax.ShapeDtypeStruct((b, h, tp, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, do, lse, dd)
+    )(qh, kh, vh, doh, lse_l, dd_l)
 
+    dkv_spec = pl.BlockSpec((None, None, block_k, d),
+                            lambda bi, hi, i, j: (bi, hi, i, 0))
     dkh, dvh = pl.pallas_call(
         functools.partial(_attn_bwd_dkv_kernel, **kernel_kw),
         grid=(b, h, sp // block_k, tp // block_q),
-        in_specs=[kk_spec(2), kk_spec(2), kq_spec(3), kq_spec(3),
-                  row_spec(3), row_spec(3)],
-        out_specs=[
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda bi, hi, i, j: (bi, i, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda bi, hi, i, j: (bi, i, hi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, sp, h, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, sp, h, d), jnp.float32),
-        ],
-        scratch_shapes=[_vmem((block_k, d), jnp.float32),
-                        _vmem((block_k, d), jnp.float32)],
+        in_specs=[k_spec(2), k_spec(2), q_spec(d, 3), q_spec(d, 3),
+                  q_spec(LANES, 3), q_spec(LANES, 3)],
+        out_specs=[dkv_spec, dkv_spec],
+        out_shape=[jax.ShapeDtypeStruct((b, h, sp, d), jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
-    )(k, v, q, do, lse, dd)
+    )(kh, vh, qh, doh, lse_l, dd_l)
 
     # GQA: per-q-head dk/dv fold back onto their kv head
-    dk = dkh[:, :s].reshape(b, s, kv, group, d).sum(3)
-    dv = dvh[:, :s].reshape(b, s, kv, group, d).sum(3)
-    return (dq[:, :t].astype(q.dtype), dk.astype(k.dtype),
-            dv.astype(v.dtype))
+    def fold(x):
+        x = x[:, :, :s].reshape(b, kv, group, s, d).sum(2)
+        return jnp.swapaxes(x, 1, 2)
 
-
-def _vmem(shape, dtype):
-    """VMEM scratch allocation (TPU); plain scratch in interpret mode."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, dtype)
-    except Exception:   # pragma: no cover — interpret-only environments
-        import jax
-        return jax.ShapeDtypeStruct(shape, dtype)
+    dq = jnp.swapaxes(dq[:, :, :t], 1, 2)
+    return (dq.astype(q.dtype), fold(dkh).astype(k.dtype),
+            fold(dvh).astype(v.dtype))

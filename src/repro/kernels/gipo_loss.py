@@ -5,8 +5,9 @@ The naive objective touches the [N, V_action] logit tensor three times
 and KL penalty. The kernels stream token blocks through VMEM once: per
 block they fuse row-max → log-sum-exp → target gather → Gaussian trust
 weight (eq. 5) → surrogate (eq. 6) → entropy → k3-KL → partial reductions,
-emitting one 8-column partial row per block. The host-side wrapper sums
-the partials — no [N, V] intermediate ever returns to HBM.
+emitting one lane-dense (8, 128) tile of partial sums per block. The
+host-side wrapper sums the partials — no [N, V] intermediate ever returns
+to HBM.
 
 Two fusion levels:
 
@@ -19,6 +20,12 @@ Two fusion levels:
     block *inside* the kernel. Forward and backward never write an
     [N, Va] tensor to HBM at all: the backward emits ``d_hidden`` per
     block and accumulates ``d_w`` across the sequential grid.
+
+TPU layout: the per-token vectors (targets, μ log-probs, advantages,
+mask) enter as ``[N, 1]`` columns — blocks ``(block_n, 1)`` whose minor
+axis equals the array's own, the orientation the ``[block_n, V]`` logits
+broadcast against. The backward's loss cotangents are scalars read from
+SMEM.
 
 Gradients are defined w.r.t. logits (resp. hidden + head weight) only;
 ``targets``/``logp_old``/``advantages``/``mask`` are treated as constants,
@@ -39,38 +46,48 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Column layout of the per-block partial sums (padded to 8 for layout):
 #   0: Σ pg        1: Σ ratio   2: Σ omega   3: Σ mask (token count)
 #   4: Σ entropy   5: Σ k3-KL   6: Σ stale   7: unused
 N_COLS = 8
+_TILE = (8, 128)     # one lane-dense partial-sum tile per token block
 
 
 # ---------------------------------------------------------------------------
 # Shared block math (pure jnp — used by kernels AND the jnp twins)
 # ---------------------------------------------------------------------------
 
+def _rowsum(x):
+    return jnp.sum(x, axis=-1, keepdims=True)
+
+
+def _expm1(x):
+    """exp(x) − 1 without cancellation near 0, from primitives the TPU
+    kernel compiler lowers (it has no ``expm1``)."""
+    return jnp.tanh(0.5 * x) * (jnp.exp(x) + 1.0)
+
+
 def _softmax_rows(logits32: jnp.ndarray, targets: jnp.ndarray):
-    """Row-streamed log-softmax pieces. logits32: [bn, V] f32; targets [bn]."""
-    row_max = jnp.max(logits32, axis=-1, keepdims=True)
-    shifted = logits32 - row_max
+    """Row-streamed log-softmax pieces. logits32: [bn, V] f32; targets
+    [bn, 1]. Per-row results are [bn, 1] columns."""
+    shifted = logits32 - jnp.max(logits32, axis=-1, keepdims=True)
     expsh = jnp.exp(shifted)
-    sumexp = jnp.sum(expsh, axis=-1)
+    sumexp = _rowsum(expsh)
     lse = jnp.log(sumexp)
-    bn, v = logits32.shape
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (bn, v), 1)
-              == targets[:, None])
-    tgt_shifted = jnp.sum(jnp.where(onehot, shifted, 0.0), axis=-1)
-    logp_new = tgt_shifted - lse                       # [bn]
-    p = expsh / sumexp[:, None]                        # [bn, V]
-    logp = shifted - lse[:, None]                      # [bn, V]
-    ent = -jnp.sum(p * logp, axis=-1)                  # [bn]
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, logits32.shape, 1)
+              == targets)
+    logp_new = _rowsum(jnp.where(onehot, shifted, 0.0)) - lse
+    p = expsh / sumexp                                 # [bn, V]
+    logp = shifted - lse                               # [bn, V]
+    ent = -_rowsum(p * logp)                           # [bn, 1]
     return p, logp, onehot, logp_new, ent
 
 
 def _fwd_partials(logits32, targets, logp_old, adv, mask, sigma: float,
                   sg=lambda x: x):
-    """One block's 8-column partial sums (see N_COLS layout).
+    """One block's partial sums (see N_COLS layout), each a (1, 1) array.
 
     ``sg``: stop-gradient hook for the trust weight's log-ratio (eq. 5).
     The Pallas kernels leave it as identity — their backward is analytic
@@ -82,14 +99,31 @@ def _fwd_partials(logits32, targets, logp_old, adv, mask, sigma: float,
     ratio = jnp.exp(lr)
     omega = jnp.exp(-0.5 * jnp.square(sg(lr) / sigma))  # eq. 5
     pg = -(omega * ratio * adv)                        # eq. 6
-    k3 = jnp.expm1(-lr) + lr                           # k3 KL estimator
+    k3 = _expm1(-lr) + lr                              # k3 KL estimator
     stale = (jnp.abs(sg(lr)) > 2.0 * sigma).astype(jnp.float32)
-    m = mask
-    return jnp.stack([
-        jnp.sum(pg * m), jnp.sum(ratio * m), jnp.sum(omega * m), jnp.sum(m),
-        jnp.sum(ent * m), jnp.sum(k3 * m), jnp.sum(stale * m),
-        jnp.zeros((), jnp.float32),
-    ])
+    total = lambda x: jnp.sum(x * mask, axis=0, keepdims=True)  # noqa: E731
+    return [total(pg), total(ratio), total(omega), total(jnp.ones_like(pg)),
+            total(ent), total(k3), total(stale)]
+
+
+def _partials_vector(parts) -> jnp.ndarray:
+    """(1, 1) partials -> the [N_COLS] vector (jnp twins)."""
+    pad = [jnp.zeros((1, 1), jnp.float32)] * (N_COLS - len(parts))
+    return jnp.concatenate(parts + pad, axis=1)[0]
+
+
+def _partials_tile(parts) -> jnp.ndarray:
+    """(1, 1) partials -> one lane-dense tile, column c holding part c."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, _TILE, 1)
+    tile = jnp.zeros(_TILE, jnp.float32)
+    for c, part in enumerate(parts):
+        tile = jnp.where(lane == c, part, tile)
+    return tile
+
+
+def _sum_tiles(tiles: jnp.ndarray) -> jnp.ndarray:
+    """[nb * 8, 128] kernel output -> the summed [N_COLS] vector."""
+    return tiles.reshape(-1, *_TILE)[:, 0, :N_COLS].sum(axis=0)
 
 
 def _block_dlogits(logits32, targets, logp_old, adv, mask, sigma: float,
@@ -107,9 +141,9 @@ def _block_dlogits(logits32, targets, logp_old, adv, mask, sigma: float,
     ratio = jnp.exp(lr)
     omega = jnp.exp(-0.5 * jnp.square(lr / sigma))
     g = (c_pg * (-(omega * ratio * adv))
-         + c_kl * (1.0 - jnp.exp(-lr))) * mask         # [bn]
-    d = g[:, None] * (onehot.astype(jnp.float32) - p)
-    d += (c_ent * mask)[:, None] * (-(p * (logp + ent[:, None])))
+         + c_kl * (1.0 - jnp.exp(-lr))) * mask         # [bn, 1]
+    d = g * (onehot.astype(jnp.float32) - p)
+    d += (c_ent * mask) * (-(p * (logp + ent)))
     return d
 
 
@@ -139,12 +173,17 @@ def _pad_rows(block_n: int, *arrays):
                  for a in arrays)
 
 
-def _row_spec(block_n: int, *trailing):
-    return pl.BlockSpec((block_n,) + trailing, lambda i: (i,) + (0,) * len(trailing))
+def _columns(*vectors):
+    """[N] per-token vectors -> [N, 1] columns."""
+    return tuple(v.reshape(-1, 1) for v in vectors)
+
+
+def _col_spec(block_n: int):
+    return pl.BlockSpec((block_n, 1), lambda i: (i, 0))
 
 
 def _zero_mask_pad(i, block_n: int, valid_n: int, mask):
-    rows = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
+    rows = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n, 1), 0)
     return jnp.where(rows < valid_n, mask, 0.0)
 
 
@@ -156,9 +195,9 @@ def _gipo_fwd_kernel(logits_ref, targets_ref, logp_old_ref, adv_ref, mask_ref,
                      out_ref, *, sigma: float, block_n: int, valid_n: int):
     i = pl.program_id(0)
     mask = _zero_mask_pad(i, block_n, valid_n, mask_ref[...])
-    out_ref[0, :] = _fwd_partials(logits_ref[...].astype(jnp.float32),
-                                  targets_ref[...], logp_old_ref[...],
-                                  adv_ref[...], mask, sigma)
+    out_ref[...] = _partials_tile(_fwd_partials(
+        logits_ref[...].astype(jnp.float32), targets_ref[...],
+        logp_old_ref[...], adv_ref[...], mask, sigma))
 
 
 def _gipo_bwd_kernel(logits_ref, targets_ref, logp_old_ref, adv_ref, mask_ref,
@@ -166,68 +205,62 @@ def _gipo_bwd_kernel(logits_ref, targets_ref, logp_old_ref, adv_ref, mask_ref,
                      valid_n: int):
     i = pl.program_id(0)
     mask = _zero_mask_pad(i, block_n, valid_n, mask_ref[...])
-    c = coef_ref[...]
     d = _block_dlogits(logits_ref[...].astype(jnp.float32), targets_ref[...],
                        logp_old_ref[...], adv_ref[...], mask, sigma,
-                       c[0, 0], c[0, 1], c[0, 2])
+                       coef_ref[0], coef_ref[1], coef_ref[2])
     dlogits_ref[...] = d.astype(dlogits_ref.dtype)
 
 
 def _gipo_fwd_call(logits, targets, logp_old, advantages, mask, sigma,
                    block_n, interpret):
     n, v = logits.shape
-    logits, targets, logp_old, advantages, mask = _pad_rows(
-        block_n, logits, targets, logp_old, advantages, mask)
+    logits, *cols = _pad_rows(
+        block_n, logits, *_columns(targets, logp_old, advantages, mask))
     grid = (logits.shape[0] // block_n,)
     kernel = functools.partial(_gipo_fwd_kernel, sigma=sigma,
                                block_n=block_n, valid_n=n)
     partials = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, v), lambda i: (i, 0)),
-            _row_spec(block_n), _row_spec(block_n), _row_spec(block_n),
-            _row_spec(block_n),
-        ],
-        out_specs=pl.BlockSpec((1, N_COLS), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], N_COLS), jnp.float32),
+        in_specs=[pl.BlockSpec((block_n, v), lambda i: (i, 0))]
+        + [_col_spec(block_n)] * 4,
+        out_specs=pl.BlockSpec(_TILE, lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((grid[0] * _TILE[0], _TILE[1]),
+                                       jnp.float32),
         interpret=interpret,
-    )(logits, targets, logp_old, advantages, mask)
-    return _finalize(partials.sum(axis=0))
+    )(logits, *cols)
+    return _finalize(_sum_tiles(partials))
 
 
 def _gipo_bwd_call(logits, targets, logp_old, advantages, mask, sigma,
                    block_n, interpret, coefs):
     n, v = logits.shape
     dtype = logits.dtype
-    logits, targets, logp_old, advantages, mask = _pad_rows(
-        block_n, logits, targets, logp_old, advantages, mask)
+    logits, *cols = _pad_rows(
+        block_n, logits, *_columns(targets, logp_old, advantages, mask))
     grid = (logits.shape[0] // block_n,)
     kernel = functools.partial(_gipo_bwd_kernel, sigma=sigma,
                                block_n=block_n, valid_n=n)
     d = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, v), lambda i: (i, 0)),
-            _row_spec(block_n), _row_spec(block_n), _row_spec(block_n),
-            _row_spec(block_n),
-            pl.BlockSpec((1, N_COLS), lambda i: (0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((block_n, v), lambda i: (i, 0))]
+        + [_col_spec(block_n)] * 4
+        + [pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((block_n, v), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((logits.shape[0], v), dtype),
         interpret=interpret,
-    )(logits, targets, logp_old, advantages, mask, coefs)
+    )(logits, *cols, coefs)
     return d[:n]
 
 
 def _loss_coefs(mask, cts) -> jnp.ndarray:
-    """Fold the (pg, ent, kl) cotangents and 1/denom into a (1, 8) row."""
+    """Fold the (pg, ent, kl) cotangents and 1/denom into an [8] vector
+    (read by the backward kernels as SMEM scalars)."""
     ct_pg, ct_ent, ct_kl, _ = cts
     denom = jnp.maximum(jnp.sum(mask), 1.0)
-    row = jnp.stack([ct_pg / denom, ct_kl / denom, ct_ent / denom,
-                     *([jnp.zeros(())] * (N_COLS - 3))])
-    return row[None, :].astype(jnp.float32)
+    return jnp.stack([ct_pg / denom, ct_kl / denom, ct_ent / denom,
+                      *([jnp.zeros(())] * (N_COLS - 3))]).astype(jnp.float32)
 
 
 def _int_zero(x):
@@ -305,8 +338,9 @@ def _policy_fwd_kernel(hidden_ref, w_ref, targets_ref, logp_old_ref, adv_ref,
     logits = jnp.dot(hidden_ref[...], w_ref[...],
                      preferred_element_type=jnp.float32)   # [bn, Va] f32
     mask = _zero_mask_pad(i, block_n, valid_n, mask_ref[...])
-    out_ref[0, :] = _fwd_partials(logits, targets_ref[...], logp_old_ref[...],
-                                  adv_ref[...], mask, sigma)
+    out_ref[...] = _partials_tile(_fwd_partials(
+        logits, targets_ref[...], logp_old_ref[...], adv_ref[...], mask,
+        sigma))
 
 
 def _policy_bwd_kernel(hidden_ref, w_ref, targets_ref, logp_old_ref, adv_ref,
@@ -322,9 +356,9 @@ def _policy_bwd_kernel(hidden_ref, w_ref, targets_ref, logp_old_ref, adv_ref,
     w32 = w_ref[...].astype(jnp.float32)
     logits = jnp.dot(h, w_ref[...], preferred_element_type=jnp.float32)
     mask = _zero_mask_pad(i, block_n, valid_n, mask_ref[...])
-    c = coef_ref[...]
     d = _block_dlogits(logits, targets_ref[...], logp_old_ref[...],
-                       adv_ref[...], mask, sigma, c[0, 0], c[0, 1], c[0, 2])
+                       adv_ref[...], mask, sigma, coef_ref[0], coef_ref[1],
+                       coef_ref[2])
     dh_ref[...] = jnp.dot(d, w32.T,
                           preferred_element_type=jnp.float32
                           ).astype(dh_ref.dtype)
@@ -337,8 +371,8 @@ def _policy_fwd_call(hidden, w, targets, logp_old, advantages, mask,
                      sigma, block_n, interpret):
     n, d = hidden.shape
     v = w.shape[1]
-    hidden, targets, logp_old, advantages, mask = _pad_rows(
-        block_n, hidden, targets, logp_old, advantages, mask)
+    hidden, *cols = _pad_rows(
+        block_n, hidden, *_columns(targets, logp_old, advantages, mask))
     grid = (hidden.shape[0] // block_n,)
     kernel = functools.partial(_policy_fwd_kernel, sigma=sigma,
                                block_n=block_n, valid_n=n)
@@ -348,22 +382,21 @@ def _policy_fwd_call(hidden, w, targets, logp_old, advantages, mask,
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),
             pl.BlockSpec((d, v), lambda i: (0, 0)),
-            _row_spec(block_n), _row_spec(block_n), _row_spec(block_n),
-            _row_spec(block_n),
-        ],
-        out_specs=pl.BlockSpec((1, N_COLS), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], N_COLS), jnp.float32),
+        ] + [_col_spec(block_n)] * 4,
+        out_specs=pl.BlockSpec(_TILE, lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((grid[0] * _TILE[0], _TILE[1]),
+                                       jnp.float32),
         interpret=interpret,
-    )(hidden, w, targets, logp_old, advantages, mask)
-    return _finalize(partials.sum(axis=0))
+    )(hidden, w, *cols)
+    return _finalize(_sum_tiles(partials))
 
 
 def _policy_bwd_call(hidden, w, targets, logp_old, advantages, mask,
                      sigma, block_n, interpret, coefs):
     n, d = hidden.shape
     v = w.shape[1]
-    hidden_p, targets, logp_old, advantages, mask = _pad_rows(
-        block_n, hidden, targets, logp_old, advantages, mask)
+    hidden_p, *cols = _pad_rows(
+        block_n, hidden, *_columns(targets, logp_old, advantages, mask))
     grid = (hidden_p.shape[0] // block_n,)
     kernel = functools.partial(_policy_bwd_kernel, sigma=sigma,
                                block_n=block_n, valid_n=n)
@@ -373,10 +406,8 @@ def _policy_bwd_call(hidden, w, targets, logp_old, advantages, mask,
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),
             pl.BlockSpec((d, v), lambda i: (0, 0)),
-            _row_spec(block_n), _row_spec(block_n), _row_spec(block_n),
-            _row_spec(block_n),
-            pl.BlockSpec((1, N_COLS), lambda i: (0, 0)),
-        ],
+        ] + [_col_spec(block_n)] * 4
+        + [pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),
             pl.BlockSpec((d, v), lambda i: (0, 0)),
@@ -386,7 +417,7 @@ def _policy_bwd_call(hidden, w, targets, logp_old, advantages, mask,
             jax.ShapeDtypeStruct((d, v), jnp.float32),
         ],
         interpret=interpret,
-    )(hidden_p, w, targets, logp_old, advantages, mask, coefs)
+    )(hidden_p, w, *cols, coefs)
     return dh[:n], dw.astype(w.dtype)
 
 

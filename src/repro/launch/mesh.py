@@ -8,7 +8,7 @@ init, while smoke tests and benches see the single real CPU device.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 # TPU v5e hardware constants (per chip) used by the roofline analysis.
 PEAK_FLOPS_BF16 = 197e12       # FLOP/s
@@ -19,16 +19,23 @@ SINGLE_POD = (16, 16)
 MULTI_POD = (2, 16, 16)
 
 
+def _auto_mesh(shape, axes) -> Mesh:
+    """A mesh whose axes GSPMD partitions (the layout the sharding rules
+    and ZeRO specs are written for); ``jax.make_mesh`` would otherwise
+    make them explicit axes that sharding-in-types checks op by op."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh() -> Mesh:
-    """1×1 mesh over the real local device(s) — smoke tests / examples."""
+    """(n, 1) data × model mesh over the real local device(s)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
 
 
 def num_chips(mesh: Mesh) -> int:

@@ -54,6 +54,7 @@ import numpy as np
 from repro.configs import ASSIGNED_ARCHS, get_config, get_shape, reduced
 from repro.configs.base import RLConfig
 from repro.launch import steps
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 
 
@@ -137,6 +138,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.resume_journal and not args.journal_dir:
         ap.error("--resume-journal needs --journal-dir")
+    enable_compile_cache()
 
     if args.pipeline:
         _run_pipeline(args)
@@ -255,8 +257,11 @@ def _run_remote_rollout(args) -> None:
     rl = RLConfig(grad_accum=1, lr_policy=1e-4, lr_value=1e-3,
                   fused_loss=args.fused_loss,
                   kernel_dispatch=args.kernel_dispatch)
+    # spawned workers roll out alone: a local worker would reach the step
+    # budget before a child has started, and the demo would show no wire
+    local = 0 if args.remote_rollout else 1
     rt = RuntimeConfig(
-        num_rollout_workers=1, inference_batch=4,
+        num_rollout_workers=local, inference_batch=4,
         transport=TransportConfig(
             remote_rollout_workers=args.remote_rollout,
             connect_rollout_workers=args.serve_workers,
@@ -278,7 +283,7 @@ def _run_remote_rollout(args) -> None:
     system = AcceRLSystem(cfg, rl, rt, suite="spatial", segment_horizon=4,
                           max_episode_steps=12, batch_episodes=4)
     host, port = system.transport_server.address
-    print(f"async system: 1 local + {args.remote_rollout} spawned + "
+    print(f"async system: {local} local + {args.remote_rollout} spawned + "
           f"{args.serve_workers} connect-mode rollout worker(s) over "
           f"{args.remote_transport} @ {host}:{port} "
           f"(restart={args.restart}"

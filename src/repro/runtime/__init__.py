@@ -41,6 +41,7 @@ from repro.runtime.scheduler import (  # noqa: F401
     BarrierScheduler,
     FreeRunScheduler,
     Scheduler,
+    ServiceFailure,
 )
 from repro.runtime.inference import InferenceService  # noqa: F401
 from repro.runtime.pipeline_exec import (  # noqa: F401

@@ -202,8 +202,11 @@ class InferenceService(Service):
             # the drain flag may have been raised while this worker was
             # parked inside _collect_window — a window carved AFTER the
             # signal is a NEW batch and must wait for the swap (update
-            # atomicity: no batch starts on stale weights mid-publish)
-            while self.store.draining and not self._stop.is_set():
+            # atomicity: no batch starts on stale weights mid-publish).
+            # The flag is up only mid-publish, so a version that landed
+            # while the window filled is taken up here too.
+            while ((self.store.draining or self.store.version() > version)
+                   and not self._stop.is_set()):
                 got = self.store.acquire(newer_than=version, timeout=0.1)
                 if got is not None:
                     params, version = got

@@ -23,14 +23,35 @@ schedulers over the one service set:
 Because the barriers live in the gate + scheduler, the rollout loop,
 inference pool, and train step are byte-for-byte the code the async mode
 runs — exactly the paper's claim that the contrast is *structural*.
+
+Both stop polling as soon as any service crashes and, once every service
+is joined, raise :class:`ServiceFailure` with the crash records — a run
+whose trainer or inference tier died never returns as a short success.
 """
 from __future__ import annotations
 
 import threading
 import time
-from typing import Dict
+from typing import Dict, List
 
 from repro.runtime.service import RolloutGate
+
+
+class ServiceFailure(RuntimeError):
+    """A scheduled run ended with crashed services.
+
+    Raised by ``Scheduler.run`` after every service is stopped and joined.
+    ``crashes`` holds each failed service's crash record
+    (``Service.crash``: service, error, traceback, ...); ``metrics`` is
+    the system metrics snapshot the run would otherwise have returned."""
+
+    def __init__(self, crashes: List[Dict], metrics: Dict):
+        self.crashes = crashes
+        self.metrics = metrics
+        first = crashes[0]
+        super().__init__(
+            f"{len(crashes)} service(s) crashed; first {first['service']!r}: "
+            f"{first['error']}\n{first.get('traceback', '')}")
 
 
 class _DynamicBarrier:
@@ -124,6 +145,17 @@ class Scheduler:
         step counter until the wall clock would hide the crash."""
         return any(s.error is not None for s in system.registry.all())
 
+    @staticmethod
+    def _finish(system, t0: float) -> Dict:
+        """The run's metrics — or :class:`ServiceFailure` carrying them
+        when any service crashed. Called once every service is joined."""
+        m = system.metrics(time.monotonic() - t0)
+        crashes = [s.crash or {"service": s.name, "error": repr(s.error)}
+                   for s in system.registry.all() if s.error is not None]
+        if crashes:
+            raise ServiceFailure(crashes, m)
+        return m
+
 
 class FreeRunScheduler(Scheduler):
     """The AcceRL mode: every service free-runs; returns system metrics."""
@@ -141,7 +173,7 @@ class FreeRunScheduler(Scheduler):
         finally:
             system.registry.stop_all()
             system.registry.join_all()
-        return system.metrics(time.monotonic() - t0)
+        return self._finish(system, t0)
 
 
 class BarrierScheduler(Scheduler):
@@ -210,4 +242,4 @@ class BarrierScheduler(Scheduler):
         finally:
             system.registry.stop_all()
             system.registry.join_all()
-        return system.metrics(time.monotonic() - t0)
+        return self._finish(system, t0)

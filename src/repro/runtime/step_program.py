@@ -86,13 +86,19 @@ class StepProgram:
         raise KeyError(f"{self.name!r} has no stage {name!r}; have "
                        f"{[s.name for s in self.stages]}")
 
-    def fused(self, *, donate: bool = False):
-        """The whole step as one jit — the single-mesh default path."""
+    def fused(self, *, donate: bool = False, state_shardings=None):
+        """The whole step as one jit — the single-mesh default path.
+
+        ``state_shardings`` pins the returned state to that placement (the
+        ZeRO layout it came in with); without it the compiler may lay the
+        updated params out like their sharded moments."""
         import jax
         if self.fused_fn is None:
             raise ValueError(f"program {self.name!r} has no fused form")
+        pinned = ({} if state_shardings is None
+                  else {"out_shardings": (state_shardings, None)})
         return jax.jit(self.fused_fn,
-                       donate_argnums=(0,) if donate else ())
+                       donate_argnums=(0,) if donate else (), **pinned)
 
     def describe(self) -> str:
         lines = [f"program {self.name} (K={self.n_micro}; "

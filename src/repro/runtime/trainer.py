@@ -26,10 +26,13 @@ import os
 import time
 from typing import Dict, List
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig, RLConfig, RuntimeConfig
-from repro.core.train_step import TrainState, init_train_state
+from repro.core.train_step import (TrainState, init_train_state,
+                                   state_shardings)
 from repro.data.prefetch import Prefetcher
 from repro.data.trajectory import TrajectoryBatch
 from repro.models.transformer import FRONTEND_DIM
@@ -89,7 +92,6 @@ class TrainerWorker(Service):
                  batch_episodes: int = 8, seed: int = 0,
                  checkpoint_dir=None, checkpoint_interval: int = 0,
                  name: str = "trainer"):
-        import jax
         super().__init__(name, role="trainer")
         self.cfg, self.rl, self.rt = cfg, rl, rt
         self.source = source
@@ -116,13 +118,18 @@ class TrainerWorker(Service):
         else:
             from repro.launch.mesh import make_local_mesh
             self._mesh = make_local_mesh()
+            mesh = self._mesh if self._mesh.devices.size > 1 else None
             self.program = step_program.build_train_step_program(
-                cfg, rl, n_micro=n_micro,
-                mesh=self._mesh if self._mesh.devices.size > 1 else None)
+                cfg, rl, n_micro=n_micro, mesh=mesh)
             self.state = init_train_state(
                 cfg, jax.random.PRNGKey(seed), mesh=self._mesh)
             self.pipeline = None
-            self._step_fn = self.program.fused(donate=False)
+            # the step updates the state in place (donated): without it a
+            # second copy of params + f32 moments is live across the step
+            self._step_fn = self.program.fused(
+                donate=True,
+                state_shardings=(state_shardings(cfg, mesh) if mesh
+                                 else None))
         self.prefetcher = Prefetcher(
             source, batch_episodes,
             functools.partial(collate_segments, metrics=self.metrics),
@@ -155,8 +162,12 @@ class TrainerWorker(Service):
     def _publish(self, version: int, step: int = 0) -> None:
         """Publish weights and open the policy-lag trace flow. The version
         is the flow id on both ends, so publish -> acquire -> first action
-        line up in the trace viewer without any shared state."""
-        self.store.publish(self.state.params, version)
+        line up in the trace viewer without any shared state.
+
+        The store gets a copy: the inference tier may still be serving a
+        published version after the next step has donated the state."""
+        self.store.publish(jax.tree.map(jnp.copy, self.state.params),
+                           version)
         if _tel is not None:
             _tel.instant("weights.publish", cat="weights", trace=version,
                          args={"version": version, "step": step},
@@ -207,6 +218,11 @@ class TrainerWorker(Service):
             if self.pipeline is not None:
                 self.state, metrics, _ = self.pipeline.run_round(
                     self.state, batch)
+            elif self._mesh.devices.size > 1:
+                # traced under the mesh so dispatched kernels run per
+                # device (kernels.dispatch._per_device)
+                with jax.set_mesh(self._mesh):
+                    self.state, metrics = self._step_fn(self.state, batch)
             else:
                 self.state, metrics = self._step_fn(self.state, batch)
             steps = int(self.metrics.inc("steps"))

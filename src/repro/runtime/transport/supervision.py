@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import multiprocessing
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -170,6 +171,34 @@ class WorkerEndpoint:
         flag in its report replies is the only lever)."""
 
 
+def parent_holds_device() -> bool:
+    """Whether this process has brought up a non-CPU JAX backend. Such a
+    process holds its accelerator until it exits: a child that opens the
+    same chip fails or hangs on the runtime's lock. Never initializes a
+    backend itself."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return False
+    import jax
+    return jax.default_backend() != "cpu"
+
+
+def check_spawn(spec: RemoteWorkerSpec) -> None:
+    """Refuse a child that would run a model — the inference tier, or a
+    rollout worker with its own colocated pool — while this process holds
+    the accelerator. Children that only step envs need no device."""
+    needs_device = spec.kind == "inference" or spec.inference == "local"
+    if needs_device and parent_holds_device():
+        raise RuntimeError(
+            f"refusing to spawn {spec.name!r}: it would run a model on the "
+            f"accelerator this process already holds, and a chip belongs "
+            f"to one process. Serve its actions from this process "
+            f"(rt.transport.inference_plane='host') or run the worker on "
+            f"another host (connect mode).")
+
+
 class SpawnedEndpoint(WorkerEndpoint):
     """PR 3's lifecycle: the worker is a child process of this host."""
 
@@ -179,6 +208,7 @@ class SpawnedEndpoint(WorkerEndpoint):
         self.process: Optional[multiprocessing.process.BaseProcess] = None
 
     def launch(self, spec: RemoteWorkerSpec) -> None:
+        check_spawn(spec)
         ctx = multiprocessing.get_context("spawn")
         self.process = ctx.Process(target=_child_entry, args=(spec,),
                                    name=spec.name, daemon=True)

@@ -223,3 +223,34 @@ def test_async_system_end_to_end():
     assert m["env_steps"] > 0
     assert m["episodes"] > 0
     assert 0 <= m["mean_policy_lag"] < 50
+
+
+def test_inference_tier_serves_published_versions():
+    """A version published between two windows — no drain flag seen, as
+    with ``rt.drain=False`` or a publish that lands while the tier is
+    busy — is served from the next window on."""
+    from repro.models.policy import init_policy_params
+    import jax
+    cfg = _tiny()
+    rt = RuntimeConfig(num_inference_workers=1, inference_batch=1,
+                       batch_buckets=(1,))
+    store = VersionedWeightStore()
+    params = init_policy_params(cfg, jax.random.PRNGKey(0))
+    store.publish(params, 0)
+    from repro.runtime import InferenceService
+    service = InferenceService(cfg, store, rt).start()
+    obs = np.zeros(12, np.int32)
+    frame = np.zeros(192, np.float32)
+    try:
+        first = service.submit(obs, frame, 0).result(timeout=120.0)
+        assert first["policy_version"] == 0
+        store.publish(params, 1)
+        deadline = time.monotonic() + 10.0
+        while time.monotonic() < deadline:
+            res = service.submit(obs, frame, 0).result(timeout=120.0)
+            if res["policy_version"] == 1:
+                break
+        assert res["policy_version"] == 1
+        assert service.metrics.gauge("weight_version") == 1.0
+    finally:
+        service.stop()

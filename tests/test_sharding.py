@@ -13,8 +13,8 @@ from repro.optim import zero
 from repro.sharding import rules
 
 MESHES = {
-    "16x16": AbstractMesh((("data", 16), ("model", 16))),
-    "2x16x16": AbstractMesh((("pod", 2), ("data", 16), ("model", 16))),
+    "16x16": AbstractMesh((16, 16), ("data", "model")),
+    "2x16x16": AbstractMesh((2, 16, 16), ("pod", "data", "model")),
 }
 
 

@@ -139,7 +139,7 @@ def test_program_declares_zero_specs():
     TP rules and moments additionally sharded over ``data``."""
     from jax.sharding import AbstractMesh
     from jax.sharding import PartitionSpec as P
-    mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     prog = build_train_step_program(CFG, RLConfig(), mesh=mesh)
     specs = prog.stage("optim_update").specs["state"]
     assert set(specs) == {"params", "moments", "scalars"}

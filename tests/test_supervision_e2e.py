@@ -18,6 +18,7 @@ import pytest
 from repro.configs import get_config, reduced
 from repro.configs.base import (RLConfig, RuntimeConfig, SupervisionConfig,
                                 TransportConfig)
+from repro.runtime import ServiceFailure
 
 
 def _system(*, spawn_workers=0, connect_workers=0, local_workers=0,
@@ -104,9 +105,12 @@ def test_budget_zero_surfaces_failed_like_pr3():
     t = threading.Thread(target=killer, daemon=True)
     t.start()
     t0 = time.monotonic()
-    m = sys_.run_async(train_steps=1_000_000, wall_timeout_s=180.0)
+    with pytest.raises(ServiceFailure) as exc:
+        sys_.run_async(train_steps=1_000_000, wall_timeout_s=180.0)
     wall = time.monotonic() - t0
     t.join(timeout=5.0)
+    m = exc.value.metrics
+    assert [c["service"] for c in exc.value.crashes] == ["remote-rollout-0"]
 
     assert wall < 150.0, "exhaustion was not contained — hit wall timeout"
     health = sys_.health()

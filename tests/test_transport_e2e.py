@@ -16,6 +16,7 @@ import pytest
 
 from repro.configs import get_config, reduced
 from repro.configs.base import RLConfig, RuntimeConfig, TransportConfig
+from repro.runtime import ServiceFailure
 
 
 def _system(*, remote_workers=1, local_workers=1, kind="socket", seed=0,
@@ -83,9 +84,12 @@ def test_remote_worker_kill_is_contained():
     t = threading.Thread(target=killer, daemon=True)
     t.start()
     t0 = time.monotonic()
-    m = sys_.run_async(train_steps=1_000_000, wall_timeout_s=180.0)
+    with pytest.raises(ServiceFailure) as exc:
+        sys_.run_async(train_steps=1_000_000, wall_timeout_s=180.0)
     wall = time.monotonic() - t0
     t.join(timeout=5.0)
+    m = exc.value.metrics
+    assert [c["service"] for c in exc.value.crashes] == ["remote-rollout-0"]
 
     assert wall < 150.0, "kill was not contained — run hit the wall timeout"
     health = sys_.health()
