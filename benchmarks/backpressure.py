@@ -49,10 +49,10 @@ The RECOVERY section (ISSUE 6) prices the resilient control plane:
 The TELEMETRY section (ISSUE 8) prices the observability plane on the
 same spawned-producer put path:
 
-  * ``off`` — REPRO_TRACE unset: the child asserts the telemetry module
-    was never even imported by the transport stack (the gate is at
-    import time, so the off path carries one ``is None`` check, nothing
-    else);
+  * ``off`` — REPRO_TRACE unset: the child asserts the recorder is off
+    and that nothing was buffered (recording is decided once at import,
+    so the off path carries one ``RECORDING`` check and a span that, in
+    this jax-free child, is a null context);
   * ``on``  — REPRO_TRACE=1: every flush runs inside a ``rollout.put``
     span with a fresh trace id riding the frame headers — the full
     per-flush cost a traced rollout worker pays.
@@ -297,36 +297,32 @@ def _telemetry_child(traced: bool, address, duration_s: float, flush: int,
                      item_floats: int, window: int, q) -> None:
     """Spawned producer for the telemetry section: the put loop of a
     traced rollout worker (per-flush span + fresh trace id on the wire)
-    vs the same loop with the recorder disarmed. Asserts the
-    import-gating contract inside the fresh interpreter: REPRO_TRACE off
-    means the transport stack never even imports the telemetry module."""
-    import sys as _sys
+    vs the same loop with the recorder off. Asserts the gating contract
+    inside the fresh interpreter: REPRO_TRACE off means nothing is
+    recorded and no ring is registered."""
+    from repro.runtime import telemetry as tel
     from repro.runtime.transport import PutStream
 
-    assert ("repro.runtime.telemetry" in _sys.modules) == traced, (
-        "telemetry import gating broken: module "
-        + ("missing with" if traced else "loaded without") + " REPRO_TRACE")
-    tel = None
-    if traced:
-        from repro.runtime import telemetry as tel
+    assert tel.RECORDING == traced, (
+        "telemetry gating broken: recording "
+        + ("off with" if traced else "on without") + " REPRO_TRACE")
     payload = [{"x": np.zeros(item_floats, np.float32),
                 "meta": {"t": 0.0, "idx": 0}}] * flush
     stream = PutStream(tuple(address), "bench", window=window)
     sent = 0
     t0 = time.monotonic()
     while time.monotonic() - t0 < duration_s:
-        if tel is not None:
-            with tel.span("rollout.put", cat="bench", trace=tel.new_id(),
-                          flow="start"):
-                stream.put_many(payload)
-        else:
+        with tel.span("rollout.put", cat="bench",
+                      trace=tel.new_id() if tel.RECORDING else None,
+                      flow="start"):
             stream.put_many(payload)
         sent += flush
     stream.flush(30.0)
     accepted = int(stream.stats()["items_accepted"])
     wall = time.monotonic() - t0
+    assert bool(tel._bufs) == traced, "untraced producer registered a ring"
     q.put({"sent": sent, "accepted": accepted, "wall": wall,
-           "events": len(tel.drain()) if tel is not None else 0})
+           "events": len(tel.drain())})
     stream.close()
 
 
@@ -703,7 +699,7 @@ def run(quick: bool = True) -> Dict:
               f"({rec['trace_events']} events recorded)")
     print(f"  telemetry: on/off put throughput x{ratio}")
     # tracing-OFF must be exactly inert (the child additionally asserts
-    # the module never imported); tracing-ON must have actually traced
+    # no ring was registered); tracing-ON must have actually traced
     assert telem["off"]["trace_events"] == 0, \
         "untraced producer recorded events — REPRO_TRACE gating broken"
     assert telem["on"]["trace_events"] > 0, \

@@ -344,10 +344,9 @@ class TelemetryConfig:
     ``sink=True`` registers a TelemetrySink service that samples every
     service's metrics/health into bounded history (and ``sink_path`` as
     JSONL), served remotely through the ``metrics.snapshot`` endpoint.
-    Span/trace RECORDING is import-gated separately by the REPRO_TRACE
-    env var (set automatically by ``launch/train.py --trace-out``) — it
-    must be decided before the hot modules import, which a config field
-    evaluated afterwards cannot do."""
+    Span/trace RECORDING is gated separately by the REPRO_TRACE env var
+    (set automatically by ``launch/train.py --trace-out``), read once when
+    ``runtime.telemetry`` is imported — before any config exists."""
 
     sink: bool = False
     sink_interval_s: float = 1.0

@@ -238,14 +238,19 @@ def _microbatches(batch: TrajectoryBatch, n_micro: int):
 # them under one jit (the fused path), and runtime/pipeline_exec.py jits
 # each one separately as a RUN instruction body — both paths execute the
 # same math, so parity is structural rather than asserted-after-the-fact.
+# Each runs under a ``jax.named_scope`` of its stage's name, so the ops of
+# the fused step carry their stage in their metadata, where a profile of
+# the device finds it.
 # --------------------------------------------------------------------------
 
+@jax.named_scope("grad_reduce")
 def zero_grads_like(params):
     """Fresh f32 accumulator matching ``params`` (one per accumulation
     window — the pipeline FREEs it after the optimizer update)."""
     return jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
 
 
+@jax.named_scope("fwd_bwd")
 def microbatch_grads(params, micro: TrajectoryBatch,
                      adv_state: AdvNormState, *, cfg: ModelConfig,
                      rl: RLConfig, remat: bool = False):
@@ -257,6 +262,7 @@ def microbatch_grads(params, micro: TrajectoryBatch,
     return grad_fn(params, micro, adv_state)
 
 
+@jax.named_scope("grad_reduce")
 def accumulate_grads(acc, grads, stats_acc, stats, n_micro: int):
     """grad_reduce stage: fold one micro-batch's grads into the f32
     accumulator (mean over the window) and sum the packed stats."""
@@ -265,6 +271,7 @@ def accumulate_grads(acc, grads, stats_acc, stats, n_micro: int):
     return acc, stats_acc + stats
 
 
+@jax.named_scope("optim_update")
 def apply_update(state: TrainState, grads, stats, metrics, *,
                  rl: RLConfig) -> Tuple[TrainState, Dict]:
     """optim_update stage: AdamW with the per-head lr tree, then fold the

@@ -39,10 +39,9 @@ import os
 import sys
 import time
 
-# --trace-out arms the observability plane. Tracing is IMPORT-gated (every
-# instrumented module binds its _tel at import time, keeping the off path
-# free), so the env flag must be up before ANY repro import below —
-# argparse has not run yet, scan argv directly.
+# --trace-out arms the observability plane. Recording is decided once, when
+# runtime.telemetry is imported, so the env flag must be up before ANY
+# repro import below — argparse has not run yet, scan argv directly.
 if any(a == "--trace-out" or a.startswith("--trace-out=")
        for a in sys.argv[1:]):
     os.environ.setdefault("REPRO_TRACE", "1")

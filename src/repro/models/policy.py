@@ -132,10 +132,13 @@ def sample_action_sequence(cfg: ModelConfig, params: Params, key,
     a = cfg.action_dim
     prefix_len = prefix_embeds.shape[1] if prefix_embeds is not None else 0
     cache_len = prefix_len + obs_tokens.shape[1] + a
-    out, cache = transformer.prefill(cfg, params, obs_tokens, prefix_embeds,
-                                     cache_len=cache_len)
-    first_logits = out["logits"][:, -1]                  # [B, Va]
+    # named scopes mark the stages in the ops' metadata (device profiles)
+    with jax.named_scope("prefill"):
+        out, cache = transformer.prefill(cfg, params, obs_tokens,
+                                         prefix_embeds, cache_len=cache_len)
+        first_logits = out["logits"][:, -1]              # [B, Va]
 
+    @jax.named_scope("decode")
     def body(carry, key_i):
         logits, cache = carry
         if temperature != 1.0:
@@ -153,7 +156,8 @@ def sample_action_sequence(cfg: ModelConfig, params: Params, key,
     tokens = tokens.T                                    # [B, A]
     logps = logps.T
     act_hidden = jnp.moveaxis(hiddens, 0, 1)             # [B, A, d]
-    value = value_head(params["value_head"], act_hidden, step_t)
+    with jax.named_scope("value_head"):
+        value = value_head(params["value_head"], act_hidden, step_t)
     return tokens, logps, value
 
 

@@ -20,18 +20,12 @@ Everything exposing ``pop_batch(n, timeout)`` is a valid trainer source
 from __future__ import annotations
 
 import abc
-import os
 import time
 from typing import Any, Dict, List, Optional
 
 from repro.data.replay import (BACKPRESSURE_POLICIES, FIFOReplayBuffer,
                                RingReplayBuffer)
-
-# Import-gated tracing (see transport.faults for the idiom).
-if os.environ.get("REPRO_TRACE"):
-    from repro.runtime import telemetry as _tel
-else:  # pragma: no cover - default path
-    _tel = None
+from repro.runtime import telemetry as _tel
 
 __all__ = ["BACKPRESSURE_POLICIES", "ExperienceChannel", "FifoChannel",
            "RingChannel", "MixedExperienceSource"]
@@ -41,7 +35,7 @@ def _trace_pop(out: Optional[List[Any]], where: str) -> None:
     """Mark a successful drain on the trace of its FIRST item: segments
     carry ``_trace`` stamped by the rollout worker, so the replay hop
     shows up on the same episode timeline as rollout.put/server.apply."""
-    if _tel is None or not out:
+    if not _tel.RECORDING or not out:
         return
     first = out[0]
     trace = first.get("_trace") if isinstance(first, dict) else None
@@ -120,7 +114,7 @@ class FifoChannel(ExperienceChannel):
     def pop_batch(self, n: int, timeout: Optional[float] = None
                   ) -> Optional[List[Any]]:
         out = self._buf.pop_batch(n, timeout=timeout)
-        if _tel is not None:
+        if _tel.RECORDING:
             _trace_pop(out, "fifo")
         return out
 
@@ -128,7 +122,7 @@ class FifoChannel(ExperienceChannel):
                  ) -> Optional[List[Any]]:
         # single lock acquisition in the buffer, not two pop_batch calls
         out = self._buf.pop_upto(max_items, timeout=timeout)
-        if _tel is not None:
+        if _tel.RECORDING:
             _trace_pop(out, "fifo")
         return out
 
@@ -243,7 +237,7 @@ class MixedExperienceSource:
             if need <= 0:
                 out, self._pending = (self._pending[:n],
                                       self._pending[n:])
-                if _tel is not None:
+                if _tel.RECORDING:
                     _trace_pop(out, "mixed")
                     self._blend_trace(out)
                 return out
@@ -268,7 +262,7 @@ class MixedExperienceSource:
             if self._pending:
                 out, self._pending = (self._pending[:max_items],
                                       self._pending[max_items:])
-                if _tel is not None:
+                if _tel.RECORDING:
                     _trace_pop(out, "mixed")
                     self._blend_trace(out)
                 return out

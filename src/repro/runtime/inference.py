@@ -21,7 +21,6 @@ one-batch-per-lockstep-tick to reproduce the synchronous step barrier.
 """
 from __future__ import annotations
 
-import os
 import queue
 import threading
 import time
@@ -34,14 +33,9 @@ import numpy as np
 from repro.configs.base import ModelConfig, RuntimeConfig
 from repro.models.policy import make_inference_fn
 from repro.models.transformer import FRONTEND_DIM
+from repro.runtime import telemetry as _tel
 from repro.runtime.service import Service
 from repro.runtime.weight_store import VersionedWeightStore
-
-# Import-gated tracing (see transport.faults for the idiom).
-if os.environ.get("REPRO_TRACE"):
-    from repro.runtime import telemetry as _tel
-else:  # pragma: no cover - default path
-    _tel = None
 
 
 class _Request:
@@ -179,7 +173,7 @@ class InferenceService(Service):
         # bridged gauge: remote workers report which policy version
         # their colocated inference pool is serving
         self.metrics.set_gauge("weight_version", float(version))
-        if _tel is not None:
+        if _tel.RECORDING:
             # middle leg of the policy-lag flow (version is the flow id)
             _tel.instant("weights.acquire", cat="weights",
                          trace=int(version),
@@ -188,82 +182,98 @@ class InferenceService(Service):
     def _run(self) -> None:
         params, version = None, -1
         while not self._stop.is_set():
-            # drain protocol: no NEW batch while the trainer is publishing
-            if self.store.draining or params is None:
-                got = self.store.acquire(newer_than=version, timeout=0.1)
-                if got is not None:
-                    params, version = got
-                    self._note_swap(version)
-                if params is None:
+            # one pass of the worker loop: a stall between its stages (the
+            # GIL, lost after resolving a batch wakes its clients) lies in
+            # this span and in none of the stages
+            with _tel.span("infer.loop"):
+                # drain protocol: no NEW batch while the trainer is publishing
+                if self.store.draining or params is None:
+                    with _tel.span("infer.swap"):
+                        got = self.store.acquire(newer_than=version,
+                                                 timeout=0.1)
+                    if got is not None:
+                        params, version = got
+                        self._note_swap(version)
+                    if params is None:
+                        continue
+                with _tel.span("infer.collect"):
+                    reqs = self._collect_window()
+                if not reqs:
                     continue
-            reqs = self._collect_window()
-            if not reqs:
-                continue
-            # the drain flag may have been raised while this worker was
-            # parked inside _collect_window — a window carved AFTER the
-            # signal is a NEW batch and must wait for the swap (update
-            # atomicity: no batch starts on stale weights mid-publish).
-            # The flag is up only mid-publish, so a version that landed
-            # while the window filled is taken up here too.
-            while ((self.store.draining or self.store.version() > version)
-                   and not self._stop.is_set()):
-                got = self.store.acquire(newer_than=version, timeout=0.1)
-                if got is not None:
-                    params, version = got
-                    self._note_swap(version)
-                    break
-            if len(reqs) == 1:
-                # a 1-item window after a non-empty wait is the shape the
-                # wait-anchoring bug produced; kept as a counter so the
-                # regression stays observable in metrics()["services"]
-                self.metrics.inc("degenerate_batches")
-            # autoscaling signal: how deep the queue still is after this
-            # window was carved off (ElasticPolicy consumes it bridged)
-            self.metrics.set_gauge("queue_depth", float(self._q.qsize()))
-            # oversized windows (window_batch > largest bucket) are split
-            # into bucket-sized chunks instead of under-padding silently
-            start = 0
-            for size in split_window(len(reqs), self.rt.batch_buckets):
-                self._run_batch(reqs[start:start + size], params, version)
-                start += size
+                # the drain flag may have been raised while this worker was
+                # parked inside _collect_window — a window carved AFTER the
+                # signal is a NEW batch and must wait for the swap (update
+                # atomicity: no batch starts on stale weights mid-publish).
+                # The flag is up only mid-publish, so a version that landed
+                # while the window filled is taken up here too.
+                with _tel.span("infer.swap"):
+                    while ((self.store.draining
+                            or self.store.version() > version)
+                           and not self._stop.is_set()):
+                        got = self.store.acquire(newer_than=version,
+                                                 timeout=0.1)
+                        if got is not None:
+                            params, version = got
+                            self._note_swap(version)
+                            break
+                if len(reqs) == 1:
+                    # a 1-item window after a non-empty wait is the shape the
+                    # wait-anchoring bug produced; kept as a counter so the
+                    # regression stays observable in metrics()["services"]
+                    self.metrics.inc("degenerate_batches")
+                # autoscaling signal: how deep the queue still is after this
+                # window was carved off (ElasticPolicy consumes it bridged)
+                self.metrics.set_gauge("queue_depth", float(self._q.qsize()))
+                # oversized windows (window_batch > largest bucket) are split
+                # into bucket-sized chunks instead of under-padding silently
+                start = 0
+                for size in split_window(len(reqs), self.rt.batch_buckets):
+                    self._run_batch(reqs[start:start + size], params, version)
+                    start += size
 
     def _run_batch(self, reqs: List[_Request], params, version: int) -> None:
-        with self.metrics.timer("busy_s"):
+        with self.metrics.timer("busy_s"), _tel.span("infer.batch"):
             n = len(reqs)
-            nb = pad_to_bucket(n, self.rt.batch_buckets)
-            self.metrics.inc("padded_slots", nb - n)
-            # autoscaling signal: fraction of the padded batch carrying
-            # real requests (low fill = idle accelerator slots)
-            self.metrics.set_gauge("window_fill", n / nb)
-            obs = np.stack([r.obs_tokens for r in reqs] +
-                           [reqs[-1].obs_tokens] * (nb - n))
-            steps = np.array([r.step for r in reqs] +
-                             [reqs[-1].step] * (nb - n), np.int32)
-            prefix = None
-            if reqs[0].frame is not None:
-                fr = np.stack([r.frame for r in reqs] +
-                              [reqs[-1].frame] * (nb - n))
-                prefix = _frame_to_prefix(fr)
-            tokens, logps, values = self._fn(params, self._next_key(),
-                                             obs, steps, prefix)
-            tokens, logps, values = (np.asarray(tokens), np.asarray(logps),
-                                     np.asarray(values))
-            for i, r in enumerate(reqs):
-                r.future.set_result({
-                    "actions": tokens[i], "logp": logps[i],
-                    "value": float(values[i]), "policy_version": version,
-                })
-            self.metrics.inc("batches")
-            self.metrics.inc("requests", n)
-            if (_tel is not None
-                    and version not in self._first_action_traced):
-                # closes the publish -> acquire -> first-action flow:
-                # the first batch served with this weight version
-                self._first_action_traced.add(version)
-                _tel.instant("infer.first_action", cat="weights",
-                             trace=int(version),
-                             args={"version": int(version), "batch": n},
-                             flow="end")
+            with _tel.span("infer.prepare"):
+                nb = pad_to_bucket(n, self.rt.batch_buckets)
+                self.metrics.inc("padded_slots", nb - n)
+                # autoscaling signal: fraction of the padded batch carrying
+                # real requests (low fill = idle accelerator slots)
+                self.metrics.set_gauge("window_fill", n / nb)
+                obs = np.stack([r.obs_tokens for r in reqs] +
+                               [reqs[-1].obs_tokens] * (nb - n))
+                steps = np.array([r.step for r in reqs] +
+                                 [reqs[-1].step] * (nb - n), np.int32)
+                prefix = None
+                if reqs[0].frame is not None:
+                    fr = np.stack([r.frame for r in reqs] +
+                                  [reqs[-1].frame] * (nb - n))
+                    prefix = _frame_to_prefix(fr)
+                key = self._next_key()
+            with _tel.span("infer.device"):
+                tokens, logps, values = self._fn(params, key, obs, steps,
+                                                 prefix)
+                tokens, logps, values = (np.asarray(tokens),
+                                         np.asarray(logps),
+                                         np.asarray(values))
+            with _tel.span("infer.resolve"):
+                for i, r in enumerate(reqs):
+                    r.future.set_result({
+                        "actions": tokens[i], "logp": logps[i],
+                        "value": float(values[i]),
+                        "policy_version": version,
+                    })
+                self.metrics.inc("batches")
+                self.metrics.inc("requests", n)
+                if (_tel.RECORDING
+                        and version not in self._first_action_traced):
+                    # closes the publish -> acquire -> first-action flow:
+                    # the first batch served with this weight version
+                    self._first_action_traced.add(version)
+                    _tel.instant("infer.first_action", cat="weights",
+                                 trace=int(version),
+                                 args={"version": int(version), "batch": n},
+                                 flow="end")
 
 
 def _frame_to_prefix(frames: np.ndarray) -> np.ndarray:

@@ -21,7 +21,6 @@ the examples, and the launchers, with the full per-service snapshot under
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -32,6 +31,7 @@ from repro.envs.toy_manipulation import TASKS_PER_SUITE, ManipulationEnv
 from repro.runtime.experience import FifoChannel, RingChannel
 from repro.runtime.inference import InferenceService
 from repro.runtime.rollout import RolloutWorker
+from repro.runtime import telemetry
 from repro.runtime.scheduler import BarrierScheduler, FreeRunScheduler
 from repro.runtime.service import ServiceRegistry
 from repro.runtime.trainer import TrainerWorker
@@ -221,16 +221,14 @@ class AcceRLSystem:
         # observability plane: a TelemetrySink samples the registry into
         # timestamped history (and serves metrics.snapshot when a
         # TransportServer is up). Armed by config or by the REPRO_TRACE
-        # env so traced runs get the sink without extra flags; the
-        # telemetry module import is deliberately lazy — untraced,
-        # unsinked runs never load it.
+        # env so traced runs get the sink without extra flags.
         tel = rt.telemetry
         self.telemetry_sink = None
-        if tel.sink or os.environ.get("REPRO_TRACE"):
-            from repro.runtime.telemetry import TelemetrySink
-            self.telemetry_sink = self.registry.register(TelemetrySink(
-                self.registry, interval_s=tel.sink_interval_s,
-                history=tel.sink_history, path=tel.sink_path))
+        if tel.sink or telemetry.RECORDING:
+            self.telemetry_sink = self.registry.register(
+                telemetry.TelemetrySink(
+                    self.registry, interval_s=tel.sink_interval_s,
+                    history=tel.sink_history, path=tel.sink_path))
             if self.transport_server is not None:
                 self.transport_server.snapshot_provider = \
                     self.telemetry_sink.sample

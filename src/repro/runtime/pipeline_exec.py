@@ -29,20 +29,14 @@ from __future__ import annotations
 import dataclasses
 import enum
 import functools
-import os
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.runtime import telemetry as _tel
 from repro.runtime.step_program import StepProgram
-
-# Import-gated tracing (see runtime/trainer.py for the idiom).
-if os.environ.get("REPRO_TRACE"):
-    from repro.runtime import telemetry as _tel
-else:  # pragma: no cover - default path
-    _tel = None
 
 
 class PipelineOp(enum.IntEnum):
@@ -387,16 +381,11 @@ class _Stream:
                 fn = self.run_fns[ins.stage]
                 args = tuple(bufs[b] for b in ins.inputs)
                 t0 = time.perf_counter()
-                if _tel is not None:
-                    with _tel.span("train.stage", cat="train",
-                                   args={"stage": ins.stage,
-                                         "submesh": self.name,
-                                         "micro": ins.micro}):
-                        out = fn(*args)
-                        out = jax.block_until_ready(out)
-                else:
-                    out = fn(*args)
-                    out = jax.block_until_ready(out)
+                with _tel.span("train.stage", cat="train",
+                               args={"stage": ins.stage,
+                                     "submesh": self.name,
+                                     "micro": ins.micro}):
+                    out = jax.block_until_ready(fn(*args))
                 self.busy_s += time.perf_counter() - t0
                 if len(ins.outputs) == 1:
                     out = (out,)
@@ -524,7 +513,7 @@ class PipelineExecutor:
         if self.metrics is not None:
             for frac in self.last_bubble.values():
                 self.metrics.observe("pipeline_bubble_frac", frac)
-        if _tel is not None:
+        if _tel.RECORDING:
             _tel.instant("pipeline.round", cat="train",
                          args={"round": self.rounds, "wall_s": wall,
                                **{f"bubble_{k}": v

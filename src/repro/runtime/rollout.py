@@ -21,7 +21,6 @@ Task selection uses Dynamic Weighted Resampling (App. D.4).
 """
 from __future__ import annotations
 
-import os
 import time
 from typing import Dict, List, Optional
 
@@ -30,14 +29,8 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.resampler import DynamicWeightedResampler
 from repro.envs.toy_manipulation import ManipulationEnv
+from repro.runtime import telemetry as _tel
 from repro.runtime.service import NULL_GATE, RolloutGate, Service
-
-# Import-gated tracing (see transport.faults for the idiom): when off,
-# the put path below carries zero extra work and zero extra keys.
-if os.environ.get("REPRO_TRACE"):
-    from repro.runtime import telemetry as _tel
-else:  # pragma: no cover - default path
-    _tel = None
 
 
 def episode_to_segments(traj: Dict[str, np.ndarray], horizon: int
@@ -188,24 +181,24 @@ class RolloutWorker(Service):
         # of one per segment (or one pipelined stream frame, in which
         # case the verdicts here are provisional and the channel's
         # stream stats carry the authoritative accept counts)
-        if _tel is not None:
+        trace = None
+        if _tel.RECORDING:
             # One trace per episode flush: the id is stamped into every
             # segment (collate only stacks named keys, so extra scalars
             # survive the channel untouched) and rides the put-frame
             # header, joining rollout.put -> server.apply -> trainer
-            # collate into one cross-process chain.
+            # collate into one cross-process chain. When not recording
+            # the put path carries no extra keys.
             trace = _tel.new_id()
             t_put = time.time()
             for seg in segments:
                 seg["_trace"] = trace
                 seg["_t_put"] = t_put
-            with _tel.span("rollout.put", cat="rollout", trace=trace,
-                           args={"worker": self.worker_id,
-                                 "segments": len(segments),
-                                 "policy_version": int(version)},
-                           flow="start"):
-                verdicts = self.experience.put_many(segments)
-        else:
+        with _tel.span("rollout.put", cat="rollout", trace=trace,
+                       args={"worker": self.worker_id,
+                             "segments": len(segments),
+                             "policy_version": int(version)},
+                       flow="start"):
             verdicts = self.experience.put_many(segments)
         self.metrics.inc("segments", float(len(segments)))
         rejected = sum(1 for v in verdicts if not v)

@@ -5,23 +5,28 @@ inference, and training proceeding without barriers. Counters can say the
 overlap exists; only a *timeline* can show where it breaks. This module is
 the span recorder behind that timeline:
 
-  * **Import-gated like ``transport/faults.py``** — hot modules do::
+  * **One span API on the profiler's clock** — :func:`span` always opens
+    a ``jax.profiler.TraceAnnotation`` named ``"repro." + name`` for its
+    extent, so a profile taken with ``jax.profiler.trace`` shows every
+    program span beside the device ops it fed. With no profile being
+    taken the annotation costs about a microsecond. ``jax.profiler`` is
+    bound lazily, on the first span in a process that has already
+    imported jax, so importing this module stays cheap and jax-free.
 
-        if os.environ.get("REPRO_TRACE"):
-            from repro.runtime import telemetry as _tel
-        else:
-            _tel = None
-
-    so with ``REPRO_TRACE`` unset this module is *never imported* and
-    every instrumentation site costs one ``is None`` check. Spawned child
+  * **Ring recording gated on ``REPRO_TRACE``** — :data:`RECORDING` is
+    read once, at import. When it is off, :func:`span` opens only the
+    annotation and :func:`instant` returns at once; instrumented modules
+    import this module plainly and put ``if _tel.RECORDING`` around the
+    work only the ring needs (id minting, header stamping). Spawned child
     processes inherit ``os.environ``, so one env var lights up the whole
     process tree.
 
-  * **Per-thread append-only ring buffers** — :func:`span` /
-    :func:`instant` append one small dict to a thread-local ring (no
-    locks on the hot path; the registration of a NEW thread's buffer is
-    the only locked step). The ring bounds memory: a long run keeps the
-    newest ``REPRO_TRACE_BUF`` events per thread.
+  * **Per-thread append-only ring buffers** — when recording,
+    :func:`span` / :func:`instant` append one small dict to a
+    thread-local ring (no locks on the hot path; the registration of a
+    NEW thread's buffer is the only locked step). The ring bounds memory:
+    a long run keeps the newest ``REPRO_TRACE_BUF`` events per thread,
+    and :func:`dump` writes how many were dropped.
 
   * **Trace context that crosses the wire** — :func:`context` installs a
     ``(trace, span)`` pair thread-locally; :func:`wire_ctx` reads it back
@@ -50,6 +55,7 @@ import contextlib
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional, Tuple
@@ -63,13 +69,24 @@ BUF_EVENTS = int(os.environ.get("REPRO_TRACE_BUF", "65536") or "65536")
 #: cap on events adopted from child processes (oldest dropped first)
 FOREIGN_EVENTS = 4 * BUF_EVENTS
 
+#: whether spans and instants are recorded into the rings (read once)
+RECORDING = bool(os.environ.get(ENV_VAR))
+
 _pid = os.getpid()
+_NULL_CTX = contextlib.nullcontext()
+_annotation_cls = None                  # jax.profiler.TraceAnnotation
 
 
-def enabled() -> bool:
-    """Whether recording is on. Gated importers never load this module
-    when off, but direct importers (tests, exporters) may call it."""
-    return bool(os.environ.get(ENV_VAR))
+def _annotation(name: str):
+    """The profiler annotation for a span named ``name``; a no-op in a
+    process that has not imported jax (it has no profiler to feed)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        if "jax" not in sys.modules:
+            return _NULL_CTX
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls("repro." + name)
 
 
 def now_us() -> int:
@@ -104,7 +121,7 @@ class _Buf:
 
     def drain(self) -> List[Dict]:
         out = self.events[self.idx:] + self.events[:self.idx]
-        self.events, self.idx = [], 0
+        self.events, self.idx, self.dropped = [], 0, 0
         return out
 
 
@@ -179,13 +196,15 @@ class _Span:
     wrapper, so no generator-contextmanager machinery."""
 
     __slots__ = ("name", "cat", "trace", "parent", "args", "flow",
-                 "sid", "buf", "t0", "_stack")
+                 "sid", "buf", "t0", "_stack", "_ann")
 
     def __init__(self, name, cat, trace, parent, args, flow):
         self.name, self.cat, self.flow = name, cat, flow
         self.trace, self.parent, self.args = trace, parent, args
 
     def __enter__(self) -> Tuple[int, int]:
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         cur = current()
         if self.trace is None:
             self.trace = cur[0] if cur else new_id()
@@ -215,24 +234,34 @@ class _Span:
             buf.append(_flow_event(self.name, self.trace, t0, self.flow,
                                    buf.tid))
         self._stack.pop()
+        self._ann.__exit__(None, None, None)
 
 
 def span(name: str, *, cat: str = "repro", trace: Optional[int] = None,
          parent: Optional[int] = None, args: Optional[Dict] = None,
-         flow: Optional[str] = None) -> _Span:
-    """Record a complete event (``ph:X``) around the body and install its
-    ``(trace, span)`` as the thread context. ``trace=None`` inherits the
-    installed context (new root trace otherwise). ``flow`` in
+         flow: Optional[str] = None):
+    """A profiler annotation ``"repro." + name`` around the body; when
+    :data:`RECORDING`, also record a complete event (``ph:X``) and install
+    its ``(trace, span)`` as the thread context. ``trace=None`` inherits
+    the installed context (new root trace otherwise). ``flow`` in
     {"start","step","end"} additionally emits a flow event with
     ``id = trace`` — the Perfetto arrow tying this slice to its
-    cross-process siblings. Yields ``(trace, span_id)``."""
+    cross-process siblings. When recording, yields ``(trace, span_id)``.
+
+    The annotation takes no keyword args: they would become part of the
+    event's name in the profile."""
+    if not RECORDING:
+        return _annotation(name)
     return _Span(name, cat, trace, parent, args, flow)
 
 
 def instant(name: str, *, cat: str = "repro", trace: Optional[int] = None,
             args: Optional[Dict] = None, flow: Optional[str] = None) -> None:
     """Record a point event (``ph:i``); same trace/flow semantics as
-    :func:`span` without a duration or context install."""
+    :func:`span` without a duration or context install. Nothing when not
+    :data:`RECORDING`."""
+    if not RECORDING:
+        return
     cur = current()
     if trace is None and cur is not None:
         trace = cur[0]
@@ -262,35 +291,44 @@ def extend_foreign(events: List[Dict]) -> None:
             _foreign_dropped += excess
 
 
-def drain(clear: bool = True) -> List[Dict]:
-    """Collect every buffered event (all threads + foreign), clearing the
-    buffers by default. ``clear=False`` copies without consuming."""
+def _collect(clear: bool) -> Tuple[List[Dict], Dict[str, int]]:
+    """Every buffered event (all threads + foreign) and how many events
+    the rings and the foreign buffer dropped since the last clear."""
+    global _foreign_dropped
     with _reg_lock:
-        bufs = list(_bufs)
+        bufs, foreign = list(_bufs), list(_foreign)
+        dropped = {"rings": sum(b.dropped for b in bufs),
+                   "foreign": _foreign_dropped}
         if clear:
-            foreign, _foreign[:] = list(_foreign), []
-        else:
-            foreign = list(_foreign)
+            _foreign[:], _foreign_dropped = [], 0
     out: List[Dict] = []
     for b in bufs:
-        if clear:
-            out.extend(b.drain())
-        else:
-            out.extend(b.events[b.idx:] + b.events[:b.idx])
-    out.extend(foreign)
-    return out
+        out.extend(b.drain() if clear
+                   else b.events[b.idx:] + b.events[:b.idx])
+    return out + foreign, dropped
+
+
+def drain(clear: bool = True) -> List[Dict]:
+    """Collect every buffered event (all threads + foreign), clearing the
+    buffers and their drop counts by default. ``clear=False`` copies
+    without consuming."""
+    return _collect(clear)[0]
 
 
 def dump(path: str, events: Optional[List[Dict]] = None,
          *, process_name: str = "") -> int:
     """Write a Chrome-trace-event JSON file (open in Perfetto). Drains
-    the buffers unless ``events`` is given. Returns the event count."""
-    if events is None:
-        events = drain()
+    the buffers unless ``events`` is given; a drained file carries the
+    drop counts as a ``trace_dropped`` metadata event, so a file whose
+    rings wrapped says it is truncated. Returns the event count."""
     meta: List[Dict] = []
     if process_name:
         meta.append({"name": "process_name", "ph": "M", "pid": _pid,
                      "tid": 0, "args": {"name": process_name}})
+    if events is None:
+        events, dropped = _collect(clear=True)
+        meta.append({"name": "trace_dropped", "ph": "M", "pid": _pid,
+                     "tid": 0, "args": dropped})
     doc = {"traceEvents": meta + events, "displayTimeUnit": "ms"}
     with open(path, "w") as f:
         json.dump(doc, f, separators=(",", ":"))
@@ -321,8 +359,7 @@ class TelemetrySink(_ServiceBase):
     per operation.
 
     Declared here (not ``service.py``) so the observability plane stays
-    one module; imported lazily by the orchestrator to keep gated-off
-    processes from loading it as a side effect.
+    one module.
     """
 
     def __init__(self, registry, *, interval_s: float = 1.0,

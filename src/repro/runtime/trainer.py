@@ -22,7 +22,6 @@ blending ``B`` and ``B_img`` when a world model is attached.
 from __future__ import annotations
 
 import functools
-import os
 import time
 from typing import Dict, List
 
@@ -36,14 +35,9 @@ from repro.core.train_step import (TrainState, init_train_state,
 from repro.data.prefetch import Prefetcher
 from repro.data.trajectory import TrajectoryBatch
 from repro.models.transformer import FRONTEND_DIM
+from repro.runtime import telemetry as _tel
 from repro.runtime.service import Service
 from repro.runtime.weight_store import VersionedWeightStore
-
-# Import-gated tracing (see transport.faults for the idiom).
-if os.environ.get("REPRO_TRACE"):
-    from repro.runtime import telemetry as _tel
-else:  # pragma: no cover - default path
-    _tel = None
 
 
 def collate_segments(segments: List[Dict[str, np.ndarray]],
@@ -55,7 +49,7 @@ def collate_segments(segments: List[Dict[str, np.ndarray]],
     (rollout.put -> server.apply -> trainer.collate) and the end-to-end
     batch age lands in the ``batch_age_s`` histogram.
     """
-    if _tel is not None:
+    if _tel.RECORDING:
         now = time.time()
         for s in segments:
             trace = s.get("_trace")
@@ -168,7 +162,7 @@ class TrainerWorker(Service):
         published version after the next step has donated the state."""
         self.store.publish(jax.tree.map(jnp.copy, self.state.params),
                            version)
-        if _tel is not None:
+        if _tel.RECORDING:
             _tel.instant("weights.publish", cat="weights", trace=version,
                          args={"version": version, "step": step},
                          flow="start")
@@ -210,32 +204,42 @@ class TrainerWorker(Service):
             self.train_on_batch(batch)
 
     def train_on_batch(self, batch: TrajectoryBatch) -> Dict:
-        with self.metrics.timer("busy_s"):
-            version = int(self.state.version)
-            lag = version - float(np.mean(batch.policy_version))
-            self.metrics.record("policy_lag", lag)
-            self.metrics.observe("policy_lag", lag)
-            if self.pipeline is not None:
-                self.state, metrics, _ = self.pipeline.run_round(
-                    self.state, batch)
-            elif self._mesh.devices.size > 1:
-                # traced under the mesh so dispatched kernels run per
-                # device (kernels.dispatch._per_device)
-                with jax.set_mesh(self._mesh):
-                    self.state, metrics = self._step_fn(self.state, batch)
-            else:
-                self.state, metrics = self._step_fn(self.state, batch)
-            steps = int(self.metrics.inc("steps"))
-            self.metrics.inc("samples", float(np.asarray(batch.mask).sum()))
-            if steps % self.rt.weight_sync_interval == 0:
-                if self.rt.drain:
-                    self.store.begin_publish()     # drain signal, App. D.6
-                self._publish(version + 1, step=steps)
-            if (self.checkpoint_dir and self.checkpoint_interval
-                    and steps % self.checkpoint_interval == 0):
-                from repro.data import checkpoint
-                checkpoint.save(self.checkpoint_dir, steps, self.state)
-        out = {k: float(v) for k, v in metrics.items()}
+        with _tel.span("trainer.step"):
+            with self.metrics.timer("busy_s"):
+                version = int(self.state.version)
+                lag = version - float(np.mean(batch.policy_version))
+                self.metrics.record("policy_lag", lag)
+                self.metrics.observe("policy_lag", lag)
+                with _tel.span("trainer.dispatch"):
+                    if self.pipeline is not None:
+                        self.state, metrics, _ = self.pipeline.run_round(
+                            self.state, batch)
+                    elif self._mesh.devices.size > 1:
+                        # traced under the mesh so dispatched kernels run
+                        # per device (kernels.dispatch._per_device)
+                        with jax.set_mesh(self._mesh):
+                            self.state, metrics = self._step_fn(self.state,
+                                                                batch)
+                    else:
+                        self.state, metrics = self._step_fn(self.state,
+                                                            batch)
+                steps = int(self.metrics.inc("steps"))
+                self.metrics.inc("samples",
+                                 float(np.asarray(batch.mask).sum()))
+                if steps % self.rt.weight_sync_interval == 0:
+                    with _tel.span("trainer.publish"):
+                        if self.rt.drain:
+                            self.store.begin_publish()  # drain, App. D.6
+                        self._publish(version + 1, step=steps)
+                if (self.checkpoint_dir and self.checkpoint_interval
+                        and steps % self.checkpoint_interval == 0):
+                    from repro.data import checkpoint
+                    with _tel.span("trainer.checkpoint"):
+                        checkpoint.save(self.checkpoint_dir, steps,
+                                        self.state)
+            # the host waits here for the step's device work to finish
+            with _tel.span("trainer.sync"):
+                out = {k: float(v) for k, v in metrics.items()}
         out["policy_lag"] = lag
         self.metrics_log.append(out)
         return out

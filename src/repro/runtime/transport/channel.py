@@ -49,6 +49,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from repro.runtime import telemetry as _tel
 from repro.runtime.experience import ExperienceChannel
 from repro.runtime.transport.codec import (decode_pytree, encode_pytree,
                                            frame_bytes, plan_pytree,
@@ -66,14 +67,6 @@ if os.environ.get("REPRO_FAULTS"):
     from repro.runtime.transport.faults import fault_point as _fault
 else:
     _fault = None
-
-# import-gated tracing (see runtime.telemetry, same idiom): when on, the
-# active trace context rides put-frame headers (``tr``/``sp``) so the
-# server can join its apply span to the producer's flush span
-if os.environ.get("REPRO_TRACE"):
-    from repro.runtime import telemetry as _tel
-else:
-    _tel = None
 
 POLL_S = 0.5          # per-RPC slice of a long pop/acquire wait
 
@@ -655,7 +648,7 @@ class PutStream:
                     waited = 0.0
             if self.closed or self.failed is not None:
                 return [False] * len(items)
-            ctx = _tel.wire_ctx() if _tel is not None else None
+            ctx = _tel.wire_ctx() if _tel.RECORDING else None
             seq = self._next_seq
             self._next_seq += 1
             self._pending[seq] = (payload, len(items), ctx,
@@ -979,7 +972,7 @@ class SocketChannel(ExperienceChannel):
             except (TransportError, OSError):
                 return False
         header = {"m": "chan.put", "chan": self.name}
-        if _tel is not None:
+        if _tel.RECORDING:
             header.update(_tel.wire_ctx())
         try:
             resp, _ = self._client.request(header, encode_pytree(item),
@@ -1005,7 +998,7 @@ class SocketChannel(ExperienceChannel):
                 return [False] * len(items)
         header = {"m": "chan.put_many", "chan": self.name,
                   "count": len(items)}
-        if _tel is not None:
+        if _tel.RECORDING:
             header.update(_tel.wire_ctx())
         try:
             resp, _ = self._client.request(header, encode_pytree(items),

@@ -53,7 +53,6 @@ Deployment shapes (``TransportConfig.inference_plane``):
 from __future__ import annotations
 
 import collections
-import os
 import threading
 import time
 import uuid
@@ -62,15 +61,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.runtime import telemetry as _tel
 from repro.runtime.service import Service
-
-# Import-gated tracing (see transport.faults for the idiom): trace ids
-# ride infer.submit headers so a broker-side span joins the caller's
-# trace across the process boundary.
-if os.environ.get("REPRO_TRACE"):
-    from repro.runtime import telemetry as _tel
-else:  # pragma: no cover - default path
-    _tel = None
 from repro.runtime.transport.channel import (POLL_S, ChannelClosed,
                                              TransportError, WireClient,
                                              shared_memory)
@@ -151,7 +143,7 @@ class InferenceBroker:
                 self._inc("dup_submits")
                 return {"ok": True, "dup": True}
             st.last_seq = seq
-        if _tel is not None and h.get("tr") is not None:
+        if _tel.RECORDING and h.get("tr") is not None:
             # joins the submitting client's trace across the wire
             _tel.instant("broker.submit", cat="inference",
                          trace=int(h["tr"]),
@@ -291,7 +283,7 @@ class RemoteInferenceClient:
         # reconnect hook runs under it and takes self._lock — registering
         # first, sending after keeps the order consistent)
         header = {"m": "infer.submit", "client": self._id, "seq": seq}
-        if _tel is not None:
+        if _tel.RECORDING:
             header.update(_tel.wire_ctx())
         try:
             self._wire.request(header, body, oob=True)

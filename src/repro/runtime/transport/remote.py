@@ -26,7 +26,6 @@ report *reply* tells a superseded incarnation to stop.
 from __future__ import annotations
 
 import dataclasses
-import os
 import random
 import sys
 import time
@@ -36,14 +35,8 @@ from repro.configs.base import (HybridConfig, ModelConfig, MoEConfig,
                                 RLConfig, RuntimeConfig, SSMConfig,
                                 SupervisionConfig, TelemetryConfig,
                                 TransportConfig)
+from repro.runtime import telemetry as _tel
 from repro.runtime.service import Service, _hist_merge
-
-# Tracing is import-gated exactly like transport.faults: when REPRO_TRACE is
-# unset the telemetry module is never imported and child spans ride nowhere.
-if os.environ.get("REPRO_TRACE"):
-    from repro.runtime import telemetry as _tel
-else:  # pragma: no cover - default path, asserted import-inert in tests
-    _tel = None
 from repro.runtime.transport.channel import (ChannelClosed, ShmChannel,
                                              SocketChannel, TransportError,
                                              WireClient)
@@ -193,7 +186,7 @@ def _build_report(services: List[Service]) -> Dict:
         "merged": _merge_snapshots([s.metrics.snapshot()
                                     for s in services]),
     }
-    if _tel is not None:
+    if _tel.RECORDING:
         # Child-side spans ride the heartbeat; the TransportServer folds
         # them into its foreign buffer so one trace.dump covers every pid.
         events = _tel.drain()

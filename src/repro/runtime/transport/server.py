@@ -69,12 +69,12 @@ just in the benchmark.
 from __future__ import annotations
 
 import collections
-import contextlib
 import os
 import socket
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro.runtime import telemetry as _tel
 from repro.runtime.service import Service
 from repro.runtime.transport.channel import shared_memory, shm_read, shm_write
 from repro.runtime.transport.codec import (decode_pytree, encode_pytree,
@@ -90,14 +90,6 @@ if os.environ.get("REPRO_FAULTS"):
     from repro.runtime.transport.faults import fault_point as _fault
 else:
     _fault = None
-
-# import-gated tracing (runtime.telemetry): the server joins producer
-# trace ids from frame headers into its own apply spans, folds child
-# trace buffers shipped via worker.report, and serves trace.dump
-if os.environ.get("REPRO_TRACE"):
-    from repro.runtime import telemetry as _tel
-else:
-    _tel = None
 
 __all__ = ["TransportServer"]
 
@@ -437,7 +429,7 @@ class TransportServer(Service):
             m = h.get("m")
             if m == "chan.put":
                 ok = self._channels[h["chan"]].put(decode_pytree(body))
-                if _tel is not None and h.get("tr") is not None:
+                if _tel.RECORDING and h.get("tr") is not None:
                     _tel.instant("server.apply", cat="transport",
                                  trace=int(h["tr"]),
                                  args={"chan": h["chan"]}, flow="step")
@@ -447,7 +439,7 @@ class TransportServer(Service):
                 chan = self._channels[h["chan"]]
                 verdicts = [bool(v) for v in
                             self._apply_put(chan, items, body)]
-                if _tel is not None and h.get("tr") is not None:
+                if _tel.RECORDING and h.get("tr") is not None:
                     _tel.instant("server.apply", cat="transport",
                                  trace=int(h["tr"]),
                                  args={"chan": h["chan"],
@@ -529,15 +521,13 @@ class TransportServer(Service):
                     # join the producer's trace: the frame header carries
                     # its flush span's ids, so this apply slice lands on
                     # the same trace id in the exported timeline
-                    apply_span = (
-                        _tel.span("server.apply", cat="transport",
-                                  trace=int(h["tr"]), parent=h.get("sp"),
-                                  args={"chan": h["chan"], "seq": seq,
-                                        "count": int(h.get("count", 0))},
-                                  flow="step")
-                        if _tel is not None and h.get("tr") is not None
-                        else contextlib.nullcontext())
-                    with apply_span:
+                    tr = h.get("tr")
+                    with _tel.span("server.apply", cat="transport",
+                                   trace=None if tr is None else int(tr),
+                                   parent=h.get("sp"),
+                                   args={"chan": h["chan"], "seq": seq,
+                                         "count": int(h.get("count", 0))},
+                                   flow=None if tr is None else "step"):
                         items = decode_pytree(body)
                         chan = self._channels[h["chan"]]
                         # a journaled channel fuses the dedup watermark
@@ -663,7 +653,7 @@ class TransportServer(Service):
                 # --trace-out) sees the whole process tree
                 trace_events = (report.pop("trace", None)
                                 if isinstance(report, dict) else None)
-                if _tel is not None and trace_events:
+                if _tel.RECORDING and trace_events:
                     _tel.extend_foreign(trace_events)
                     self.metrics.inc("trace_events_folded",
                                      float(len(trace_events)))
@@ -696,7 +686,7 @@ class TransportServer(Service):
             if m == "trace.dump":
                 # every buffered span this process holds — including
                 # child-process events folded from worker.report payloads
-                if _tel is None:
+                if not _tel.RECORDING:
                     return {"ok": True, "enabled": False, "events": []}, b""
                 return {"ok": True, "enabled": True,
                         "events": _tel.drain(

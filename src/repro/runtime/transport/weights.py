@@ -21,24 +21,15 @@ no code change on its side, which is the whole point of the seam.
 """
 from __future__ import annotations
 
-import contextlib
-import os
 import time
 from typing import Any, Optional, Tuple
 
+from repro.runtime import telemetry as _tel
 from repro.runtime.transport.channel import (ChannelClosed, WireClient,
                                              long_poll)
 from repro.runtime.transport.codec import decode_pytree, encode_pytree
 
-# Import-gated tracing (see transport.faults for the idiom).
-if os.environ.get("REPRO_TRACE"):
-    from repro.runtime import telemetry as _tel
-else:  # pragma: no cover - default path
-    _tel = None
-
 __all__ = ["WeightStoreTransport"]
-
-_NULL_CTX = contextlib.nullcontext()
 
 
 class WeightStoreTransport:
@@ -154,7 +145,7 @@ class WeightStoreTransport:
                 if not resp.get("ok"):
                     return None
                 version = int(resp["version"])
-        if _tel is not None:
+        if _tel.RECORDING:
             # wire leg of the policy-lag flow (version is the flow id):
             # a remote pool's fetch shows up on the publish timeline
             _tel.instant("weights.wire_acquire", cat="weights",
@@ -171,11 +162,10 @@ class WeightStoreTransport:
 
     def publish(self, params: Any, version: int) -> None:
         blob = encode_pytree(params)
-        with (_tel.span("weights.wire_publish", cat="weights",
-                        trace=int(version),
-                        args={"version": int(version),
-                              "bytes": len(blob)}, flow="start")
-              if _tel is not None else _NULL_CTX):
+        with _tel.span("weights.wire_publish", cat="weights",
+                       trace=int(version),
+                       args={"version": int(version),
+                             "bytes": len(blob)}, flow="start"):
             self._client.request({"m": "store.publish", "version": version},
                                  blob, oob=self._use_shm)
         self._state = (-float("inf"), *self._state[1:])

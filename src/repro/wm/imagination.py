@@ -16,8 +16,6 @@ imagination worker generates a full τ̂ batch per device dispatch —
 """
 from __future__ import annotations
 
-import contextlib
-import os
 import time
 from typing import Dict
 
@@ -27,15 +25,9 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, WMConfig
 
-# Import-gated tracing (see transport.faults for the idiom).
-if os.environ.get("REPRO_TRACE"):
-    from repro.runtime import telemetry as _tel
-else:  # pragma: no cover - default path
-    _tel = None
-
-_NULL_CTX = contextlib.nullcontext()
 from repro.models.policy import sample_action_sequence
 from repro.models.transformer import FRONTEND_DIM
+from repro.runtime import telemetry as _tel
 from repro.runtime.service import Service
 from repro.wm import denoiser as dn
 from repro.wm import reward as rw
@@ -163,11 +155,10 @@ class ImaginationWorker(Service):
             # the imagined batch's trace id: the policy version it was
             # dreamed under, so wm.imagine lines up with the
             # weights.publish flow on the Perfetto timeline
-            with (_tel.span("wm.imagine", cat="wm", trace=int(version),
-                            args={"batch": self.batch,
-                                  "horizon": self.wm.imagine_horizon,
-                                  "version": int(version)}, flow="step")
-                  if _tel is not None else _NULL_CTX):
+            with _tel.span("wm.imagine", cat="wm", trace=int(version),
+                           args={"batch": self.batch,
+                                 "horizon": self.wm.imagine_horizon,
+                                 "version": int(version)}, flow="step"):
                 with self.metrics.timer("busy_s"):
                     out = self._fn(params, self.wm_params_ref["obs"],
                                    self.wm_params_ref["reward"], sub, tokens,

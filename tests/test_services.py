@@ -123,8 +123,8 @@ def test_fifo_channel_block_waits_for_consumer():
     ch.put(0)
     t = threading.Thread(target=lambda: (time.sleep(0.05),
                                          ch.pop_batch(1, timeout=1.0)))
-    t.start()
-    t0 = time.monotonic()
+    t0 = time.monotonic()              # before the start: the pop comes
+    t.start()                          # 0.05 s after t0 at the earliest
     assert ch.put(1)                   # blocks until the pop frees a slot
     assert time.monotonic() - t0 >= 0.04
     t.join()

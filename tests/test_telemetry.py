@@ -12,9 +12,11 @@ import os
 import pathlib
 import subprocess
 import sys
+import textwrap
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from repro.configs import get_config, reduced
@@ -41,40 +43,74 @@ def _clean_telemetry():
 
 
 # ---------------------------------------------------------------------------
-# import gating: REPRO_TRACE unset must keep telemetry entirely unloaded
+# gating: REPRO_TRACE decides recording once; importing stays jax-free
 # ---------------------------------------------------------------------------
 
+INSTRUMENTED = ("repro.runtime.rollout", "repro.runtime.trainer",
+                "repro.runtime.inference", "repro.runtime.experience",
+                "repro.runtime.pipeline_exec", "repro.wm.imagination",
+                "repro.runtime.transport.channel",
+                "repro.runtime.transport.server",
+                "repro.runtime.transport.remote",
+                "repro.runtime.transport.weights",
+                "repro.runtime.transport.inference_plane")
+
+
 def test_trace_gating_is_import_inert():
-    """The hot modules must not even IMPORT telemetry when REPRO_TRACE is
-    unset; with it set, they must all bind a live _tel."""
-    prog = ("import sys;"
-            "import repro.runtime.rollout;"
-            "import repro.runtime.trainer;"
-            "import repro.runtime.experience;"
-            "import repro.runtime.transport.channel;"
-            "import repro.runtime.transport.server;"
-            "import repro.runtime.transport.remote;"
-            "import repro.runtime.transport.weights;"
-            "import repro.runtime.transport.inference_plane;"
-            "mod='repro.runtime.telemetry';"
-            "assert (mod in sys.modules) == (%r), sorted(sys.modules)")
-    src = str(pathlib.Path(__file__).resolve().parent.parent / "src")
-    for gated in (False, True):
+    """Off: nothing is buffered and no ring is registered, and importing
+    telemetry (with its service base) does not import jax. On: every
+    instrumented module records through its binding."""
+    src = pathlib.Path(__file__).resolve().parent.parent / "src"
+    record = textwrap.dedent("""
+        import importlib
+        mods = [importlib.import_module(m) for m in %r]
+        from repro.runtime import telemetry as tel
+        on = %r
+        assert tel.RECORDING == on
+        for m in mods:
+            assert m._tel is tel, m.__name__
+            with m._tel.span(m.__name__):
+                m._tel.instant("mark")
+        events = tel.drain()
+        assert bool(tel._bufs) == on, tel._bufs
+        names = {e["name"] for e in events if e["ph"] == "X"}
+        assert names == ({m.__name__ for m in mods} if on else set()), names
+        """)
+    # the package's __init__ loads the runtime (and jax): stand it in, so
+    # that only telemetry and what it imports are loaded
+    bare = textwrap.dedent("""
+        import sys, types
+        pkg = types.ModuleType("repro.runtime")
+        pkg.__path__ = [%r]
+        sys.modules["repro.runtime"] = pkg
+        import repro.runtime.telemetry as tel
+        with tel.span("x"):
+            pass
+        assert "jax" not in sys.modules, "telemetry imported jax"
+        """) % str(src / "repro" / "runtime")
+    for on in (False, True):
         env = {k: v for k, v in os.environ.items() if k != "REPRO_TRACE"}
-        env["PYTHONPATH"] = src
+        env["PYTHONPATH"] = str(src)
         env["JAX_PLATFORMS"] = "cpu"
-        if gated:
+        if on:
             env["REPRO_TRACE"] = "1"
-        proc = subprocess.run([sys.executable, "-c", prog % gated],
-                              env=env, capture_output=True, text=True)
-        assert proc.returncode == 0, proc.stderr
+        for prog in (record % (INSTRUMENTED, on), bare):
+            proc = subprocess.run([sys.executable, "-c", prog], env=env,
+                                  capture_output=True, text=True)
+            assert proc.returncode == 0, proc.stderr
+
+
+@pytest.fixture
+def recording(monkeypatch):
+    """Spans and instants record into the rings, as under REPRO_TRACE."""
+    monkeypatch.setattr(telemetry, "RECORDING", True)
 
 
 # ---------------------------------------------------------------------------
 # span recorder: context, rings, flows, Chrome export
 # ---------------------------------------------------------------------------
 
-def test_span_context_inheritance_and_wire_ctx():
+def test_span_context_inheritance_and_wire_ctx(recording):
     assert telemetry.wire_ctx() == {}
     with telemetry.span("outer", flow="start") as (trace, sid):
         assert telemetry.current() == (trace, sid)
@@ -93,14 +129,14 @@ def test_span_context_inheritance_and_wire_ctx():
     assert flows and flows[0]["id"] == trace and "bp" not in flows[0]
 
 
-def test_instant_flow_step_binds_enclosing():
+def test_instant_flow_step_binds_enclosing(recording):
     telemetry.instant("hop", trace=42, flow="step")
     events = telemetry.drain()
     flow = next(e for e in events if e["ph"] == "t")
     assert flow["id"] == 42 and flow["bp"] == "e"
 
 
-def test_ring_buffer_bounds_memory(monkeypatch):
+def test_ring_buffer_bounds_memory(monkeypatch, recording):
     monkeypatch.setattr(telemetry, "BUF_EVENTS", 8)
     for i in range(20):
         telemetry.instant(f"e{i}")
@@ -117,7 +153,7 @@ def test_extend_foreign_bounded(monkeypatch):
     assert len(got) == 4 and got[-1]["name"] == "f9"
 
 
-def test_dump_writes_chrome_trace_format(tmp_path):
+def test_dump_writes_chrome_trace_format(tmp_path, recording):
     with telemetry.span("work", cat="test", args={"k": 1}, flow="start"):
         telemetry.instant("mark", trace=7)
     out = tmp_path / "trace.json"
@@ -125,16 +161,162 @@ def test_dump_writes_chrome_trace_format(tmp_path):
     doc = json.loads(out.read_text())
     assert set(doc) == {"traceEvents", "displayTimeUnit"}
     events = doc["traceEvents"]
-    assert len(events) == n + 1                 # + process_name metadata
+    assert len(events) == n + 2                 # + two metadata events
     assert events[0] == {"name": "process_name", "ph": "M",
                          "pid": os.getpid(), "tid": 0,
                          "args": {"name": "unit"}}
-    for e in events[1:]:
+    assert events[1] == {"name": "trace_dropped", "ph": "M",
+                         "pid": os.getpid(), "tid": 0,
+                         "args": {"rings": 0, "foreign": 0}}
+    for e in events[2:]:
         assert {"name", "ph", "ts", "pid"} <= set(e)
         assert isinstance(e["ts"], int)
     sl = next(e for e in events if e.get("ph") == "X")
     assert sl["dur"] >= 1 and sl["args"]["k"] == 1
     assert telemetry.drain() == []              # dump drained the buffers
+
+
+def test_dump_reports_dropped_events_and_drain_resets(tmp_path, monkeypatch,
+                                                      recording):
+    """A dumped file whose rings wrapped says how many events it lost;
+    the counts start again from zero after the drain."""
+    monkeypatch.setattr(telemetry, "BUF_EVENTS", 4)
+    monkeypatch.setattr(telemetry, "FOREIGN_EVENTS", 2)
+    for i in range(10):
+        telemetry.instant(f"e{i}")
+    telemetry.extend_foreign([{"name": f"f{i}", "ph": "i"} for i in range(5)])
+    out = tmp_path / "wrapped.json"
+    telemetry.dump(str(out))
+    meta = [e for e in json.loads(out.read_text())["traceEvents"]
+            if e.get("name") == "trace_dropped"]
+    assert meta == [{"name": "trace_dropped", "ph": "M", "pid": os.getpid(),
+                     "tid": 0, "args": {"rings": 6, "foreign": 3}}]
+    telemetry.instant("after")
+    telemetry.dump(str(out))
+    meta = [e for e in json.loads(out.read_text())["traceEvents"]
+            if e.get("name") == "trace_dropped"]
+    assert meta[0]["args"] == {"rings": 0, "foreign": 0}
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock, stage scopes in the compiled programs
+# ---------------------------------------------------------------------------
+
+def _tiny_cfg():
+    import dataclasses
+    cfg = reduced(get_config("deepseek-7b"), layers=2, d_model=64)
+    return dataclasses.replace(cfg, num_prefix_tokens=1)
+
+
+def _profile_spans(trace_dir):
+    """[(name, start_ns, end_ns)] of the ``repro.`` spans of each host
+    thread in the profile written under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    path = next(pathlib.Path(trace_dir).rglob("*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            spans = [(e.name, e.start_ns, e.end_ns) for e in line.events
+                     if e.name.startswith("repro.")]
+            if spans:
+                out.append(spans)
+    return out
+
+
+def _children(spans, parent):
+    """Names, in order, of the spans inside each ``parent`` span."""
+    found = []
+    for name, s, e in spans:
+        if name == parent:
+            found.append([n for n, cs, ce in sorted(spans, key=lambda x: x[1])
+                          if n != parent and s <= cs and ce <= e])
+    return found
+
+
+def test_program_spans_land_in_the_profile(tmp_path):
+    """One InferenceService batch and one ``train_on_batch`` under the
+    profiler: the program's spans are in the trace, each layer nested in
+    its batch or step, on the thread that fed the device."""
+    import jax
+    from repro.data.trajectory import dummy_batch
+    from repro.models.policy import init_policy_params
+    from repro.runtime.inference import InferenceService
+    from repro.runtime.trainer import TrainerWorker
+    from repro.runtime.weight_store import VersionedWeightStore
+
+    cfg = _tiny_cfg()
+    store = VersionedWeightStore()
+    store.publish(init_policy_params(cfg, jax.random.PRNGKey(0)), 0)
+    service = InferenceService(cfg, store, RuntimeConfig(
+        num_inference_workers=1, inference_batch=2,
+        inference_max_wait_s=0.5, batch_buckets=(2,)))
+    rl = RLConfig(grad_accum=2, lr_policy=1e-4, lr_value=1e-3)
+    trainer = TrainerWorker(cfg, rl, RuntimeConfig(), None,
+                            VersionedWeightStore(), batch_episodes=2)
+    trainer.begin_inline()
+    batch = dummy_batch(2, 3, 12, cfg.action_dim, cfg.vocab_size,
+                        cfg.action_vocab_size, num_prefix=1)
+    rng = np.random.default_rng(0)
+    obs = rng.integers(0, cfg.vocab_size, (2, 12)).astype(np.int32)
+    frame = rng.random((2, 192)).astype(np.float32)
+    with jax.profiler.trace(str(tmp_path)):
+        service.start()
+        try:
+            futs = [service.submit(obs[i], frame[i], 0) for i in range(2)]
+            for f in futs:
+                f.result(timeout=120.0)
+        finally:
+            service.stop()
+            service.join(timeout=30.0)
+        trainer.train_on_batch(batch)
+    spans = _profile_spans(tmp_path)
+    infer = [v for v in spans if any(n == "repro.infer.batch"
+                                     for n, _, _ in v)]
+    train = [v for v in spans if any(n == "repro.trainer.step"
+                                     for n, _, _ in v)]
+    assert len(infer) == 1 and len(train) == 1, spans
+    assert {n for n, _, _ in infer[0]} == {
+        "repro.infer.loop", "repro.infer.swap", "repro.infer.collect",
+        "repro.infer.batch", "repro.infer.prepare", "repro.infer.device",
+        "repro.infer.resolve"}
+    # every stage lies inside a pass of the worker loop
+    loops = [(s, e) for n, s, e in infer[0] if n == "repro.infer.loop"]
+    for n, s, e in infer[0]:
+        assert any(ls <= s and e <= le for ls, le in loops), n
+    assert _children(infer[0], "repro.infer.batch")[0] == [
+        "repro.infer.prepare", "repro.infer.device", "repro.infer.resolve"]
+    assert _children(train[0], "repro.trainer.step") == [[
+        "repro.trainer.dispatch", "repro.trainer.publish",
+        "repro.trainer.sync"]]
+
+
+def test_stage_scopes_in_lowered_programs():
+    """The fused train step and the inference fn carry their stages as
+    named scopes, which the compiled ops keep as metadata."""
+    import jax
+    from repro.core.train_step import init_train_state
+    from repro.data.trajectory import dummy_batch
+    from repro.models.policy import make_inference_fn
+    from repro.runtime.step_program import build_train_step_program
+
+    cfg = _tiny_cfg()
+    rl = RLConfig(grad_accum=2)
+    state = init_train_state(cfg, jax.random.PRNGKey(0))
+    batch = dummy_batch(2, 3, 12, cfg.action_dim, cfg.vocab_size,
+                        cfg.action_vocab_size, num_prefix=1)
+    step = build_train_step_program(cfg, rl).fused()
+    text = step.lower(state, batch).as_text(debug_info=True)
+    for scope in ("fwd_bwd", "grad_reduce", "optim_update"):
+        assert f"{scope}/" in text, scope
+    obs = np.zeros((2, 12), np.int32)
+    prefix = np.zeros((2, 1, 1024), np.float32)
+    text = make_inference_fn(cfg).lower(
+        state.params, jax.random.PRNGKey(0), obs, np.zeros((2,), np.int32),
+        prefix).as_text(debug_info=True)
+    for scope in ("prefill", "decode", "value_head"):
+        assert f"{scope}/" in text, scope
 
 
 # ---------------------------------------------------------------------------
