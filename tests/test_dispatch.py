@@ -267,8 +267,14 @@ def test_ssd_backward_kernel_grad_parity(p):
 
 @pytest.mark.parametrize("b,s,h,kv,d", [
     (1, 128, 4, 4, 64),          # MHA, cache == block
-    (2, 200, 8, 2, 64),          # GQA, ragged cache (kernel pads)
+    (2, 200, 8, 2, 64),          # GQA, 200 slots in one block
     (3, 33, 4, 1, 64),           # MQA, tiny cache
+    (4, 20, 8, 8, 64),           # the serve cell's MHA call scaled down
+    (3, 20, 8, 4, 64),           # GQA, group 2
+    (3, 20, 8, 2, 64),           # GQA, group 4
+    (2, 20, 12, 1, 64),          # MQA, 12 members on one KV head
+    (2, 1100, 8, 4, 64),         # cache streamed in blocks, ragged last
+    (5, 400, 4, 4, 64),          # batch not a multiple of the rows a step
 ])
 def test_decode_attention_dispatch_parity(b, s, h, kv, d):
     """The Pallas decode kernel matches the jnp twin bit-for-shape on
@@ -298,6 +304,46 @@ def test_decode_attention_masks_invalid_slots():
         with dispatch.forced(mode):
             out = dispatch.decode_attention(q, k, v, valid)
         _close(out[:, 0], v[:, 7], rtol=2e-5, atol=2e-5)
+
+
+def test_decode_attention_masks_invalid_slots_last_block():
+    """The only live slot lies in the last, ragged block of a streamed
+    cache: every earlier block is masked whole, and the online softmax
+    still ends on that slot alone."""
+    from repro.kernels.decode_attention import _tiles
+    b, s, h, d = 2, 1100, 4, 64
+    block_s = _tiles(b, s, h, h, d, 4)[1]
+    assert block_s < s and s % block_s
+    q = jnp.asarray(RNG.standard_normal((b, 1, h, d)), jnp.float32)
+    k = jnp.asarray(RNG.standard_normal((b, s, h, d)), jnp.float32)
+    v = jnp.asarray(RNG.standard_normal((b, s, h, d)), jnp.float32)
+    valid = jnp.zeros((b, s), bool).at[:, s - 1].set(True)
+    for mode in ("pallas", "jnp"):
+        with dispatch.forced(mode):
+            out = dispatch.decode_attention(q, k, v, valid)
+        _close(out[:, 0], v[:, s - 1], rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("b,s,h,kv,d,streamed,ragged_rows", [
+    (2, 1100, 8, 4, 64, True, False),
+    (5, 400, 4, 4, 64, False, True),
+])
+def test_decode_attention_tiles(b, s, h, kv, d, streamed, ragged_rows):
+    """The parity cases above reach the tilings they are there for: a
+    cache split into blocks with a ragged last one, and a batch whose
+    last block of rows is short (float32 caches, as the tests run)."""
+    from repro.kernels.decode_attention import _tiles
+    rows, block_s = _tiles(b, s, h, kv, d, 4)
+    assert (block_s < s and s % block_s != 0) == streamed
+    assert (b % rows != 0) == ragged_rows
+
+
+def test_decode_attention_tiles_serve_call():
+    """The serve cell's call (batch 32, 20 cache slots, 32 bf16 heads of
+    128) takes the whole cache of several rows a step."""
+    from repro.kernels.decode_attention import _tiles
+    rows, block_s = _tiles(32, 20, 32, 32, 128, 2)
+    assert block_s == 20 and 4 <= rows <= 8
 
 
 @pytest.mark.parametrize("t,h,kv", [(16, 4, 4), (100, 8, 2)])

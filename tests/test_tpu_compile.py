@@ -13,6 +13,7 @@ import importlib
 import importlib.util
 import os
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +80,18 @@ def test_decode_attention(shape):
     _compile(lambda q, k, v, b: decode_attention(q, k, v, b),
              shape((8, 1, H, D)), shape((8, 300, H, D)),
              shape((8, 300, H, D)), shape((8, 300), jnp.float32))
+    # the serve cell's call: the kernel reads the cache as it is stored,
+    # so no bf16 array is made around it but views of its operands
+    serve = _compile(decode_attention, shape((32, 1, H, D)),
+                     shape((32, 20, H, D)), shape((32, 20, H, D)),
+                     shape((32, 20), jnp.float32))
+    made = [line for line in serve.as_text().splitlines()
+            if re.search(r"= bf16\[", line)
+            and not re.search(r" (parameter|bitcast|custom-call)\(", line)]
+    assert not made, made
+    # GQA at internlm2-1.8b's widths: 16 heads over 8 KV heads
+    _compile(decode_attention, shape((32, 1, 16, D)), shape((32, 20, 8, D)),
+             shape((32, 20, 8, D)), shape((32, 20), jnp.float32))
 
 
 def _token_args(shape, n):
