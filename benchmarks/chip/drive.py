@@ -3,8 +3,11 @@
 A runner builds the system under test from a configuration file and a
 traffic mix, warms every shape the window uses, measures for the window,
 reads the device's peak memory, frees the program's state and then runs
-the plain reference on what the timed path produced. It returns an
-:class:`Outcome`; ``run.py`` turns that into the result line.
+the plain reference on what the timed path produced. Every piece that
+depends on the model's layers (its ``ModelConfig``, weights, reference and
+work counts) comes from the configuration's family module
+(``families/``). It returns an :class:`Outcome`; ``run.py`` turns that
+into the result line.
 """
 from __future__ import annotations
 
@@ -16,16 +19,16 @@ import os
 import tempfile
 import threading
 import time
+from types import ModuleType
 from typing import Dict, List, Optional
 from unittest import mock
 
 import numpy as np
 
-import flops
-import reference
+import families
 import trace_reduce
 import traffic_gen
-import weights
+from reference import ADAM_B1
 
 
 @dataclasses.dataclass
@@ -37,6 +40,11 @@ class Ctx:
     trace: bool
     t_start: float                      # time.monotonic() at process start
     controls: bool = False              # also read the precision control
+    family: Optional[ModuleType] = None  # the configuration's, when None
+
+    def __post_init__(self):
+        if self.family is None:
+            self.family = families.load(self.config)
 
 
 @dataclasses.dataclass
@@ -50,6 +58,11 @@ class Outcome:
     work: Dict[str, float]              # flops / bytes of the window's work
     memory_peak_bytes: int
     summary: Optional[trace_reduce.Summary] = None
+    # the program's own readings over the window: serve, the change of each
+    # counter of the tier's registry and ``<histogram>.count`` / ``.sum``;
+    # train, the mean over the window's steps of each scalar that
+    # ``train_on_batch`` returns
+    program: Dict[str, float] = dataclasses.field(default_factory=dict)
     control: Dict[str, Dict[str, float]] = dataclasses.field(
         default_factory=dict)
     notes: List[str] = dataclasses.field(default_factory=list)
@@ -59,33 +72,20 @@ class Outcome:
 # the system under test, as the configuration file states it
 # ---------------------------------------------------------------------------
 
-def model_config(config: Dict):
-    """The program's ModelConfig for a configuration file: the registry
-    arch with every size the file states."""
-    from repro.configs import get_config
-    from repro.models.transformer import FRONTEND_DIM
-    ph = config["policy_head"]
-    if ph["frontend_dim"] != FRONTEND_DIM:
-        raise ValueError(f"frontend_dim {ph['frontend_dim']} is not the "
-                         f"program's stub frontend width {FRONTEND_DIM}")
-    d, h = config["hidden_size"], config["num_attention_heads"]
-    cfg = dataclasses.replace(
-        get_config(config["arch"]),
-        num_layers=config["num_hidden_layers"], d_model=d, num_heads=h,
-        num_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        norm_eps=config["rms_norm_eps"], rope_theta=config["rope_theta"],
-        action_vocab_size=ph["action_vocab_size"],
-        action_dim=ph["action_dim"],
-        max_episode_steps=ph["max_episode_steps"],
-        num_prefix_tokens=ph["num_prefix_tokens"],
-        param_dtype=config["torch_dtype"],
-        compute_dtype=config["torch_dtype"],
-        head_dim_override=(None if config["head_dim"] * h == d
-                           else config["head_dim"]))
-    if cfg.head_dim != config["head_dim"]:
-        raise ValueError(f"head_dim {cfg.head_dim} != {config['head_dim']}")
-    return cfg
+def make_params(family: ModuleType, config: Dict, seed32: int):
+    """The family's weights for ``config`` from a 32-bit seed, made on the
+    default device in one jitted call."""
+    import jax
+    fn = jax.jit(functools.partial(family.draw_params, config))
+    return fn(jax.random.PRNGKey(seed32))
+
+
+def window_work(n: float, model_flops: float,
+                kernels: Dict[str, float]) -> Dict[str, float]:
+    """The work of ``n`` optimizer steps or answered requests, each of
+    ``model_flops`` and of the family's per-kernel ``kernels``."""
+    return {"model_flops": n * model_flops,
+            **{k: n * v for k, v in kernels.items()}}
 
 
 def check_params(params, cfg) -> None:
@@ -126,12 +126,20 @@ class _Tracer:
             import jax
             jax.profiler.stop_trace()
 
-    def summary(self, sites: Dict[str, str]) -> trace_reduce.Summary:
+    def summary(self, texts: List[str]) -> trace_reduce.Summary:
+        """The window's reduction, with kernels and stage scopes found in
+        the compiled texts of the programs it ran."""
         import shutil
+        sites: Dict[str, str] = {}
+        scopes: Dict[str, str] = {}
+        for text in texts:
+            sites.update(trace_reduce.kernel_sites(text))
+            scopes.update(trace_reduce.scope_sites(text))
         try:
             path = glob.glob(os.path.join(self.dir, "**", "*.xplane.pb"),
                              recursive=True)[0]
-            return trace_reduce.summarize(trace_reduce.load(path), sites)
+            return trace_reduce.summarize(trace_reduce.load(path), sites,
+                                          scopes)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 
@@ -261,17 +269,17 @@ def _named(tree) -> Dict[str, float]:
 def run_train(ctx: Ctx) -> Outcome:
     from repro.data.trajectory import TrajectoryBatch
 
-    mix, config = ctx.mix, ctx.config
+    mix, config, fam = ctx.mix, ctx.config, ctx.family
     if mix["segments"] != mix["rl"]["micro_batch"] * mix["rl"]["grad_accum"]:
         raise ValueError("segments must be micro_batch x grad_accum")
-    cfg = model_config(config)
+    cfg = fam.model_config(config)
     rng = np.random.default_rng([ctx.seed, 1])
     checked = [traffic_gen.train_batch(rng, mix, config)
                for _ in range(mix["checked_steps"])]
     pool = [traffic_gen.train_batch(rng, mix, config)
             for _ in range(mix["pool_batches"])]
     s32 = traffic_gen.seed32(ctx.seed)
-    params = weights.make_params(config, s32)
+    params = make_params(fam, config, s32)
     check_params(params, cfg)
     trainer = build_trainer(cfg, mix, s32, params)
     del params
@@ -284,13 +292,14 @@ def run_train(ctx: Ctx) -> Outcome:
         with _span("trainer.train_on_batch"):
             prog_steps.append(trainer.train_on_batch(TrajectoryBatch(**b)))
         if i == 0:
-            prog_grad = {k: v / (1.0 - reference.ADAM_B1) for k, v in
+            prog_grad = {k: v / (1.0 - ADAM_B1) for k, v in
                          _named(norms(trainer.state.opt.mu)).items()}
-    p0 = weights.make_params(config, s32)
+    p0 = make_params(fam, config, s32)
     prog_change = _named(diff_norms(trainer.state.params, p0))
     del p0
 
     tracer = _Tracer(ctx.trace)
+    logged = len(trainer.metrics_log)
     steps, t0 = 0, time.monotonic()
     setup_s = t0 - ctx.t_start
     with tracer:
@@ -306,34 +315,36 @@ def run_train(ctx: Ctx) -> Outcome:
             t1 = time.monotonic()
     window_s = t1 - t0
     peak = memory_peak()
+    window_log = trainer.metrics_log[logged:]
+    program = {k: float(np.mean([m[k] for m in window_log]))
+               for k in (window_log[0] if window_log else {})}
     summary = None
     if ctx.trace:
         text = trainer._step_fn.lower(
             trainer.state, TrajectoryBatch(**pool[0])).compile().as_text()
-        summary = tracer.summary(trace_reduce.kernel_sites(text))
+        summary = tracer.summary([text])
     del trainer
     gc.collect()
 
-    spec = reference.Spec.from_config(config)
+    spec = fam.Spec.from_config(config)
     rl = mix["rl"]
-    ref = reference.train_reference(weights.make_params(config, s32),
-                                    checked, rl, spec)
+    ref = fam.train_reference(make_params(fam, config, s32), checked, rl,
+                              spec)
     readings = train_readings(prog_steps, prog_grad, prog_change, ref, rl)
     out_control = {}
     if ctx.controls:
         for name, kw in (("control_fp8", {"prec": "fp8"}),
                          ("fault_half_batch", {"half_batch": True})):
-            got = reference.train_reference(
-                weights.make_params(config, s32), checked, rl, spec, **kw)
+            got = fam.train_reference(make_params(fam, config, s32),
+                                      checked, rl, spec, **kw)
             out_control[name] = train_readings(
                 got["steps"], got["first_grad"], got["change"], ref, rl)
 
-    seq = flops.seq_shape(config, mix["instruction_tokens"])
+    seq = fam.seq_shape(config, mix["instruction_tokens"])
     rows = mix["segments"] * (mix["horizon"] + 1)
-    step_flops = flops.train_step_flops(config, mix["segments"],
-                                        mix["horizon"],
-                                        mix["instruction_tokens"])
-    flash = flops.flash_train(config, rows, seq["tokens"])
+    step_flops = fam.train_step_flops(config, mix["segments"],
+                                      mix["horizon"],
+                                      mix["instruction_tokens"])
     tokens = rows * seq["tokens"]
     e2e = {"train_tokens_per_s": steps * tokens / window_s} if steps else {}
     e2e["setup_s"] = setup_s
@@ -348,11 +359,9 @@ def run_train(ctx: Ctx) -> Outcome:
     return Outcome(
         e2e=e2e, attempted=steps, failed=0, readings=readings,
         window_s=window_s, counters={"steps": steps, "tokens": steps * tokens},
-        work={"model_flops": steps * step_flops,
-              "flash_flops": steps * flash["flops"],
-              "flash_bytes": steps * flash["bytes"]},
+        work=window_work(steps, step_flops, fam.kernel_work(config, mix)),
         memory_peak_bytes=peak, summary=summary, control=out_control,
-        notes=notes)
+        program=program, notes=notes)
 
 
 # ---------------------------------------------------------------------------
@@ -400,8 +409,8 @@ class Client(threading.Thread):
                 obs = self.env.reset(int(self.tasks[episode % len(self.tasks)]))
 
 
-def check_requests(config: Dict, params, sample: List[tuple],
-                   controls: bool):
+def check_requests(fam: ModuleType, config: Dict, params,
+                   sample: List[tuple], controls: bool):
     """Readings of served (observation, result) pairs against the
     reference's teacher-forced pass with the benchmark's weights (and the
     float8 control's, with ``controls``)."""
@@ -417,13 +426,13 @@ def check_requests(config: Dict, params, sample: List[tuple],
               "value": np.array([r["value"] for _, r in sample], np.float32)}
     prefix = np.zeros((len(sample), 1, FRONTEND_DIM), np.float32)
     prefix[:, 0, :frame.shape[1]] = frame
-    spec = reference.Spec.from_config(config)
-    ref_lp, ref_v = reference.serve_readings(
+    spec = fam.Spec.from_config(config)
+    ref_lp, ref_v = fam.serve_readings(
         params, obs, served["actions"], steps, prefix, spec)
     readings = serve_readings(served, ref_lp, ref_v)
     control = {}
     if controls:
-        lp8, v8 = reference.serve_readings(
+        lp8, v8 = fam.serve_readings(
             params, obs, served["actions"], steps, prefix, spec, prec="fp8")
         control["control_fp8"] = serve_readings(
             {"logp": lp8, "value": v8}, ref_lp, ref_v)
@@ -431,8 +440,14 @@ def check_requests(config: Dict, params, sample: List[tuple],
 
 
 def _serve_counters(inf) -> Dict[str, float]:
-    return {k: inf.metrics.counter(k)
-            for k in ("requests", "batches", "padded_slots")}
+    """Every counter of the tier's registry, and the count and sum of
+    every histogram as ``<name>.count``, ``<name>.sum``, in one snapshot."""
+    snap = inf.metrics.snapshot()
+    out = dict(snap["counters"])
+    for name, h in snap["hists"].items():
+        out[f"{name}.count"] = h["count"]
+        out[f"{name}.sum"] = h["sum"]
+    return out
 
 
 def _at_batch_end(inf, timeout: float = 60.0):
@@ -454,10 +469,10 @@ def run_serve(ctx: Ctx) -> Outcome:
     from repro.runtime.inference import InferenceService
     from repro.runtime.weight_store import VersionedWeightStore
 
-    mix, config = ctx.mix, ctx.config
-    cfg = model_config(config)
+    mix, config, fam = ctx.mix, ctx.config, ctx.family
+    cfg = fam.model_config(config)
     s32 = traffic_gen.seed32(ctx.seed)
-    params = weights.make_params(config, s32)
+    params = make_params(fam, config, s32)
     check_params(params, cfg)
     store = VersionedWeightStore()
     store.publish(params, 1)
@@ -497,12 +512,10 @@ def run_serve(ctx: Ctx) -> Outcome:
     peak = memory_peak()
     summary = None
     if ctx.trace:
-        sites = {}
-        for nb in buckets:
-            text = inf._fn.lower(params, jax.random.PRNGKey(0),
-                                 *args[nb]).compile().as_text()
-            sites.update(trace_reduce.kernel_sites(text))
-        summary = tracer.summary(sites)
+        summary = tracer.summary([
+            inf._fn.lower(params, jax.random.PRNGKey(0),
+                          *args[nb]).compile().as_text()
+            for nb in buckets])
     failed = sum(c.failed for c in clients) + sum(c.is_alive()
                                                   for c in clients)
     crash = inf.error
@@ -519,13 +532,14 @@ def run_serve(ctx: Ctx) -> Outcome:
     take = rng.choice(len(done), min(mix["checked_requests"], len(done)),
                       replace=False)
     readings, out_control = check_requests(
-        config, params, [done[i][2:] for i in np.sort(take)], ctx.controls)
+        fam, config, params, [done[i][2:] for i in np.sort(take)],
+        ctx.controls)
     del params
 
-    d = {k: c1[k] - c0[k] for k in c0}
-    prompt = flops.seq_shape(config, t_obs)
-    new = prompt["actions"]
-    dec = flops.decode_request(config, prompt["prefix"] + t_obs, new)
+    program = {k: v - c0.get(k, 0.0) for k, v in c1.items()}
+    d = {k: program.get(k, 0.0)
+         for k in ("requests", "batches", "padded_slots")}
+    new = fam.seq_shape(config, t_obs)["actions"]
     e2e = {"setup_s": setup_s}
     if d["requests"] and len(done):
         e2e["actions_per_s"] = d["requests"] / window_s
@@ -544,12 +558,11 @@ def run_serve(ctx: Ctx) -> Outcome:
         failed=failed + (crash is not None),
         readings=readings, window_s=window_s,
         counters={"answered": len(done), **d},
-        work={"model_flops": d["requests"]
-              * flops.serve_request_flops(config, t_obs),
-              "decode_flops": d["requests"] * dec["flops"],
-              "decode_bytes": d["requests"] * dec["bytes"]},
+        work=window_work(d["requests"],
+                         fam.serve_request_flops(config, t_obs),
+                         fam.kernel_work(config, mix)),
         memory_peak_bytes=peak, summary=summary, control=out_control,
-        notes=notes)
+        program=program, notes=notes)
 
 
 RUNNERS = {"train": run_train, "serve": run_serve}

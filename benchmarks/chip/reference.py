@@ -9,6 +9,11 @@ normalisation, the GIPO surrogate with the k3 KL term, and AdamW with
 global-norm clipping. It reads the parameter pytree the program keeps
 (same leaf names and shapes) and nothing the program computed.
 
+The parts that do not depend on the layer's shape (``rmsnorm``, ``rope``,
+``value_head``, ``gipo_loss``, ``adamw_update``, the precision control
+``mm``) are what another family's reference reuses; ``train_reference``,
+``serve_readings`` and ``serve_block`` take the decoder as ``decoder=``.
+
 Parameters are stored in the dtype the configuration states (bfloat16
 weights, float32 value head); every operation runs in float32 on the
 upcast values, and each AdamW result is stored back in the leaf's dtype,
@@ -172,9 +177,12 @@ def value_head(p, act_hidden, steps, prec):
     return (mm("nd,do->no", z, p["mlp_w2"], prec) + p["mlp_b2"])[:, 0]
 
 
-def score(params, table, ids, actions, prefix, spec: Spec, prec):
+def score(params, table, ids, actions, prefix, spec: Spec, prec,
+          decoder=decoder):
     """Teacher-forced pass over [prefix, instruction ids, action ids]; the
     action tokens go through the shared embedding table as ids 0..Va-1.
+    ``decoder(params, table, ids, prefix, spec, prec)`` gives the
+    final-norm hidden states.
 
     Returns (log-softmax over the Va bins at each action token [N, A, Va],
     the hiddens at the action tokens [N, A, d]). Action token k is read at
@@ -200,21 +208,24 @@ def action_logp(logp_all, action_tokens):
 SERVE_ROWS = 64
 
 
-def serve_block(params, ids, toks, steps, prefix, *, spec: Spec, prec: str):
+def serve_block(params, ids, toks, steps, prefix, *, spec: Spec, prec: str,
+                decoder=decoder):
     """Teacher-forced log-probs [N, A] of the action tokens ``toks`` and
     the values [N] of one block of requests."""
     with jax.default_matmul_precision("highest"):
         logp_all, act_h = score(params, params["embed"]["table"], ids,
-                                toks, prefix, spec, prec)
+                                toks, prefix, spec, prec, decoder)
         v = value_head(params["value_head"], act_h, steps, prec)
         return action_logp(logp_all, toks), v
 
 
 def serve_readings(params, obs, actions, steps, prefix, spec: Spec,
-                   prec: str = "f32", rows: int = SERVE_ROWS):
+                   prec: str = "f32", rows: int = SERVE_ROWS, *,
+                   decoder=decoder):
     """Teacher-forced log-probs [N, A] of the served action tokens and the
     values [N], in blocks of ``rows`` requests (the last block padded)."""
-    block = jax.jit(functools.partial(serve_block, spec=spec, prec=prec))
+    block = jax.jit(functools.partial(serve_block, spec=spec, prec=prec,
+                                      decoder=decoder))
     n = len(obs)
     pad = -n % rows
     grow = lambda x: np.concatenate(  # noqa: E731
@@ -266,18 +277,29 @@ def _welford(state, stats):
             m2 + (sq - n * bm * bm) + delta * delta * count * n / total)
 
 
-def micro_loss(params, table, mb, adv_state, rl: Dict, spec: Spec, prec):
+def micro_loss(params, table, mb, adv_state, rl: Dict, spec: Spec, prec,
+               decoder=decoder):
     """Loss of one micro-batch; ``mb`` holds numpy-shaped arrays with ids
     already mapped into ``table``'s rows."""
     b, tp1 = mb["obs_tokens"].shape[:2]
-    t = tp1 - 1
     flat = lambda x: x.reshape((b * tp1,) + x.shape[2:])
     logp_all, act_h = score(params, table, flat(mb["ids"]),
                             flat(mb["action_ids"]), flat(mb["prefix_embeds"]),
-                            spec, prec)
+                            spec, prec, decoder)
     values = value_head(params["value_head"], act_h, flat(mb["steps"]),
                         prec).reshape(b, tp1)
     logp_all = logp_all.reshape(b, tp1, spec.action_dim, -1)
+    return gipo_loss(logp_all, values, mb, adv_state, rl)
+
+
+def gipo_loss(logp_all, values, mb, adv_state, rl: Dict):
+    """The GIPO objective of one micro-batch from its teacher-forced
+    log-softmax over the action bins [B, T+1, A, Va] and values [B, T+1]
+    (the last row bootstraps): JIT-GAE, the lagged advantage
+    normalisation, the surrogate, the k3 KL, entropy and the value loss.
+    Returns (loss, (metrics, advantage statistics))."""
+    t = values.shape[1] - 1
+    action_dim = logp_all.shape[2]
     adv, returns = gae(jax.lax.stop_gradient(values), mb["rewards"],
                        mb["dones"], rl["discount"], rl["gae_lambda"])
     mask = mb["mask"]
@@ -291,7 +313,7 @@ def micro_loss(params, table, mb, adv_state, rl: Dict, spec: Spec, prec):
     omega = jnp.exp(-0.5 * jnp.square(jax.lax.stop_gradient(log_ratio)
                                       / rl["gipo_sigma"]))
     m = mask[..., None]
-    denom = jnp.maximum(jnp.sum(m) * spec.action_dim, 1.0)
+    denom = jnp.maximum(jnp.sum(m) * action_dim, 1.0)
     pg = jnp.sum(-(omega * ratio * adv_n[..., None]) * m) / denom
     pg_scale = jnp.sum(omega * ratio * jnp.abs(adv_n[..., None]) * m) / denom
     kl = jnp.sum((jnp.expm1(-log_ratio) + log_ratio) * m) / denom
@@ -306,18 +328,51 @@ def micro_loss(params, table, mb, adv_state, rl: Dict, spec: Spec, prec):
                     "kl": kl, "entropy": ent, "pg_scale": pg_scale}, stats)
 
 
-def micro_grads(p32, mb, adv_state, rl: Dict, spec: Spec, prec: str):
+def micro_grads(p32, mb, adv_state, rl: Dict, spec: Spec, prec: str,
+                decoder=decoder):
     """Gradients of one micro-batch's loss with respect to the float32
     parameters, whose embedding is the ``rows`` the batches read."""
     def loss(p32):
         body = {k: v for k, v in p32.items() if k != "rows"}
-        return micro_loss(body, p32["rows"], mb, adv_state, rl, spec, prec)
+        return micro_loss(body, p32["rows"], mb, adv_state, rl, spec, prec,
+                          decoder)
     with jax.default_matmul_precision("highest"):
         return jax.grad(loss, has_aux=True)(p32)
 
 
+def _lr(rl: Dict, path_keys, step):
+    base = rl["lr_value"] if "value_head" in path_keys else rl["lr_policy"]
+    return base * jnp.minimum((step + 1.0) / max(rl["warmup_steps"], 1), 1.0)
+
+
+def adamw_update(stored, mu, nu, grads, step, *, rl: Dict):
+    """One AdamW step with global-norm clipping and linear warm-up (the
+    value head at its own rate) on float32 gradients; each parameter is
+    stored back in its leaf's dtype. Returns (params, mu, nu, clipped
+    gradients, pre-clip global norm)."""
+    leaves = jax.tree.leaves(grads)
+    gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in leaves))
+    clip = jnp.minimum(1.0, rl["max_grad_norm"] / jnp.maximum(gnorm, 1e-9))
+    grads = jax.tree.map(lambda g: g * clip, grads)
+    s1 = step + 1.0
+    mu = jax.tree.map(lambda m, g: ADAM_B1 * m + (1 - ADAM_B1) * g, mu, grads)
+    nu = jax.tree.map(lambda v, g: ADAM_B2 * v + (1 - ADAM_B2) * g * g,
+                      nu, grads)
+
+    def upd(path, p, m, v):
+        keys = [getattr(k, "key", "") for k in path]
+        delta = (m / (1 - ADAM_B1 ** s1)) / (
+            jnp.sqrt(v / (1 - ADAM_B2 ** s1)) + ADAM_EPS)
+        return (p.astype(jnp.float32) - _lr(rl, keys, step) * delta
+                ).astype(p.dtype)
+
+    new = jax.tree_util.tree_map_with_path(upd, stored, mu, nu)
+    return new, mu, nu, grads, gnorm
+
+
 def train_reference(params, batches: Sequence[Dict], rl: Dict, spec: Spec,
-                    *, prec: str = "f32", half_batch: bool = False) -> Dict:
+                    *, prec: str = "f32", half_batch: bool = False,
+                    decoder=decoder) -> Dict:
     """Run ``len(batches)`` optimizer steps from ``params``.
 
     Returns per-step metrics, the per-leaf norms of the first step's
@@ -336,37 +391,12 @@ def train_reference(params, batches: Sequence[Dict], rl: Dict, spec: Spec,
                               jnp.asarray(ids_used), axis=0)
     n_micro = rl["grad_accum"]
 
-    def lr_for(path_keys, step):
-        base = rl["lr_value"] if "value_head" in path_keys else rl["lr_policy"]
-        return base * jnp.minimum((step + 1.0) / max(rl["warmup_steps"], 1),
-                                  1.0)
-
     @jax.jit
     def grads_of(p32, mb, adv_state):
-        return micro_grads(p32, mb, adv_state, rl, spec, prec)
+        return micro_grads(p32, mb, adv_state, rl, spec, prec, decoder)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-    def update(stored, mu, nu, grads, step):
-        leaves = jax.tree.leaves(grads)
-        gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in leaves))
-        clip = jnp.minimum(1.0, rl["max_grad_norm"] / jnp.maximum(gnorm,
-                                                                  1e-9))
-        grads = jax.tree.map(lambda g: g * clip, grads)
-        s1 = step + 1.0
-        mu = jax.tree.map(lambda m, g: ADAM_B1 * m + (1 - ADAM_B1) * g,
-                          mu, grads)
-        nu = jax.tree.map(lambda v, g: ADAM_B2 * v + (1 - ADAM_B2) * g * g,
-                          nu, grads)
-
-        def upd(path, p, m, v):
-            keys = [getattr(k, "key", "") for k in path]
-            delta = (m / (1 - ADAM_B1 ** s1)) / (
-                jnp.sqrt(v / (1 - ADAM_B2 ** s1)) + ADAM_EPS)
-            return (p.astype(jnp.float32) - lr_for(keys, step) * delta
-                    ).astype(p.dtype)
-
-        new = jax.tree_util.tree_map_with_path(upd, stored, mu, nu)
-        return new, mu, nu, grads, gnorm
+    update = jax.jit(functools.partial(adamw_update, rl=rl),
+                     donate_argnums=(0, 1, 2))
 
     def micro_batches(batch):
         mbsz = batch["obs_tokens"].shape[0] // n_micro

@@ -3,10 +3,10 @@
     JAX_PLATFORMS=cpu python3 benchmarks/chip/rehearse.py [cell ...]
 
 For every cell of ``BENCHMARK.json`` (all, or those named): the jitted
-weights, then for a train cell the program's donated train step at the
-mix's shapes and the reference's gradient program, and for a serve cell
-the program's inference fn at each batch bucket and the reference's
-serve block, through the TPU compiler on a v5e described by
+weights of the configuration's family, then for a train cell the
+program's donated train step at the mix's shapes, and for a serve cell
+the program's inference fn at each batch bucket, then the family's
+reference programs (``reference_programs``), through the TPU compiler on a v5e described by
 ``jax.experimental.topologies``. Prints each program's
 ``memory_analysis()``. Nothing runs on a chip, so this says nothing about
 results or times; it refuses what the chip's compiler would refuse and
@@ -41,9 +41,8 @@ def main(names) -> int:
     from jax.sharding import SingleDeviceSharding
 
     import drive
-    import reference
+    import families
     import traffic_gen
-    import weights
     from repro.core.train_step import init_train_state
     from repro.data.trajectory import TrajectoryBatch
     from repro.kernels import dispatch
@@ -67,14 +66,14 @@ def main(names) -> int:
                             .read_text())
         mix = json.loads((HERE / "traffic" / f"{cell['traffic']}.json")
                          .read_text())
-        cfg = drive.model_config(config)
-        shapes = place(weights.param_shapes(config))
+        fam = families.load(config)
+        cfg = fam.model_config(config)
+        draw = jax.jit(functools.partial(fam.draw_params, config))
+        shapes = place(jax.eval_shape(draw, jax.random.PRNGKey(0)))
         pbytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
-        c = jax.jit(functools.partial(weights._make, config)).lower(
-            place(jax.random.PRNGKey(0))).compile()
+        c = draw.lower(place(jax.random.PRNGKey(0))).compile()
         print(f"{cell['name']} weights ({pbytes} bytes): {_mem(c)}",
               flush=True)
-        spec = reference.Spec.from_config(config)
         if mix["entry"] == "train":
             rl = drive._rl(mix)
             batch = traffic_gen.train_batch(np.random.default_rng(0), mix,
@@ -86,24 +85,6 @@ def main(names) -> int:
                 c = step.lower(state, place(TrajectoryBatch(**batch))
                                ).compile()
             print(f"{cell['name']} train step: {_mem(c)}", flush=True)
-            rows = reference.used_rows([batch] * mix["checked_steps"],
-                                       spec.vocab)
-            p32 = {k: v for k, v in weights.param_shapes(config).items()
-                   if k != "embed"}
-            p32["rows"] = jax.ShapeDtypeStruct((len(rows), spec.d),
-                                               jnp.float32)
-            p32 = place(jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32), p32))
-            mb = {k: v[:mix["rl"]["micro_batch"]] for k, v in batch.items()
-                  if k != "policy_version"}
-            mb["ids"] = mb["obs_tokens"]
-            mb["action_ids"] = mb["actions"]
-            adv = place((np.float32(0), np.float32(0), np.float32(0)))
-            c = jax.jit(lambda p, m, a: reference.micro_grads(
-                p, m, a, mix["rl"], spec, "f32")).lower(
-                    p32, place(mb), adv).compile()
-            print(f"{cell['name']} reference micro-batch grads: {_mem(c)}",
-                  flush=True)
         else:
             t = mix["instruction_tokens"]
             with dispatch.forced("pallas"):
@@ -117,17 +98,9 @@ def main(names) -> int:
                     ).compile()
                     print(f"{cell['name']} inference fn (batch {nb}): "
                           f"{_mem(c)}", flush=True)
-            n = reference.SERVE_ROWS
-            a = config["policy_head"]["action_dim"]
-            c = jax.jit(functools.partial(
-                reference.serve_block, spec=spec, prec="f32")).lower(
-                    shapes, place(np.zeros((n, t), np.int32)),
-                    place(np.zeros((n, a), np.int32)),
-                    place(np.zeros((n,), np.int32)),
-                    place(np.zeros((n, 1, FRONTEND_DIM), np.float32))
-                ).compile()
-            print(f"{cell['name']} reference serve block ({n} rows): "
-                  f"{_mem(c)}", flush=True)
+        for label, fn, args in fam.reference_programs(config, mix):
+            c = fn.lower(*place(args)).compile()
+            print(f"{cell['name']} {label}: {_mem(c)}", flush=True)
     return 0
 
 

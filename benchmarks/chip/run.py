@@ -4,12 +4,14 @@
         --seconds <s> --trace <0|1>
 
 The cell, its configuration file and its traffic mix are found by name in
-``BENCHMARK.json`` at the root of the checkout. One process holds the chip
-from start to end: it exits non-zero without a result when JAX finds no
-TPU, fewer chips than the cell asks for, or a device missing from
-``peaks.json``. It makes the weights from the seed, warms every shape,
-measures for ``--seconds``, then checks what the timed path produced
-against the plain reference (``reference.py``) and prints, as the last
+``BENCHMARK.json`` at the root of the checkout, the configuration's
+architecture family in ``families/<family>.py``. One process holds the
+chip from start to end: it exits non-zero without a result when the
+family has no file, JAX finds no TPU, fewer chips than the cell asks for,
+or a device missing from ``peaks.json``. It makes the weights from the
+seed, warms every shape, measures for ``--seconds``, then checks what the
+timed path produced against the family's plain reference and prints, as
+the last
 line of standard output, one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics with ``--trace 0``,
 its per-layer metrics with ``--trace 1``), ``device`` and, last, the
@@ -66,9 +68,9 @@ def load_cell(name: str):
     return bench, cell, config, mix, limits
 
 
-def metrics_for(bench, cell, trace: bool):
+def metrics_for(bench, cell, trace: bool, where=HERE / "metrics"):
     """The cell's end-to-end metrics (``--trace 0``) or per-layer metrics
-    (``--trace 1``), each with its reader."""
+    (``--trace 1``), each with its reader ``<where>/<name>.py``."""
     reported = {m["name"] for m in bench["end_to_end"]
                 if cell["name"] in m.get("workloads", [cell["name"]])}
     if not trace:
@@ -79,13 +81,24 @@ def metrics_for(bench, cell, trace: bool):
         if cell["name"] not in m.get("workloads", [cell["name"]]) \
                 or m["moves"] not in reported:
             continue
-        path = HERE / "metrics" / f"{m['name']}.py"
+        path = where / f"{m['name']}.py"
         spec = importlib.util.spec_from_file_location(
             "metric_" + m["name"].replace(".", "_"), path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         out.append((m, mod.read))
     return out
+
+
+def read_metrics(out, pairs, peak):
+    """{name: {value, unit}} of the metrics whose reader (or, for an
+    end-to-end metric, the runner) gave a number."""
+    metrics = {}
+    for m, read in pairs:
+        value = out.e2e.get(m["name"]) if read is None else read(out, peak)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    return metrics
 
 
 def check_device(chips: int):
@@ -127,9 +140,11 @@ def main(argv=None) -> int:
         bench, cell, config, mix, limits = load_cell(args.workload)
         sys.path[:0] = [str(ROOT / "src"), str(HERE)]
         os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        import families
+        family = families.load(config)
         devices = check_device(cell["chips"])
         peak = device_peak(devices[0].device_kind)
-    except (BenchError, OSError, KeyError, ValueError) as e:
+    except (BenchError, OSError, LookupError, ValueError) as e:
         print(f"run.py: {e}", file=sys.stderr)
         return 3
     from repro.launch.compile_cache import enable_compile_cache
@@ -140,14 +155,11 @@ def main(argv=None) -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     ctx = drive.Ctx(config=config, mix=mix,
                     seed=args.seed, seconds=args.seconds,
-                    trace=bool(args.trace), t_start=T_START)
+                    trace=bool(args.trace), t_start=T_START, family=family)
     out = drive.RUNNERS[mix["entry"]](ctx)
 
-    metrics = {}
-    for m, read in metrics_for(bench, cell, bool(args.trace)):
-        value = out.e2e.get(m["name"]) if read is None else read(out, peak)
-        if value is not None:
-            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    metrics = read_metrics(out, metrics_for(bench, cell, bool(args.trace)),
+                           peak)
     dev = devices[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices),
@@ -170,8 +182,15 @@ def main(argv=None) -> int:
     print(f"end-to-end: {json.dumps(out.e2e)}")
     print(f"window counters: {json.dumps(out.counters)}; work: "
           f"{json.dumps(out.work)}")
+    print(f"program readings: {json.dumps(out.program)}")
     if out.summary is not None:
-        print(f"kernel seconds: {json.dumps(out.summary.kernel_s)}")
+        s = out.summary
+        print(f"kernel seconds: {json.dumps(s.kernel_s)}")
+        print("program spans: " + json.dumps({
+            k: getattr(s, k) for k in (
+                "span_count", "scope_s", "unscoped_ops", "idle_in_s",
+                "idle_attributed_s", "serve_host_idle_s",
+                "dispatch_on_clock")}))
     print(f"readings: {json.dumps(out.readings)}")
     for name, vals in out.control.items():
         print(f"{name}: {json.dumps(vals)}")

@@ -29,12 +29,12 @@ ROOT = CHIP.parents[1]
 sys.path[:0] = [str(ROOT / "src"), str(CHIP)]
 
 import drive  # noqa: E402
+import families  # noqa: E402
 import flops  # noqa: E402
 import reference  # noqa: E402
 import run  # noqa: E402
 import trace_reduce as tr  # noqa: E402
 import traffic_gen  # noqa: E402
-import weights  # noqa: E402
 
 
 def _json(path):
@@ -171,8 +171,9 @@ def test_reference_matches_program_policy(name):
     import jax
     from repro.models.policy import policy_forward
     config = tiny_config(name, torch_dtype="float32")
-    cfg = drive.model_config(config)
-    params = weights.make_params(config, 7)
+    fam = families.load(config)
+    cfg = fam.model_config(config)
+    params = drive.make_params(fam, config, 7)
     drive.check_params(params, cfg)
     spec = reference.Spec.from_config(config)
     rng = np.random.default_rng(0)
