@@ -12,6 +12,7 @@ from typing import Dict, List
 from repro.configs.base import (  # noqa: F401  (re-exported)
     INPUT_SHAPES,
     HybridConfig,
+    MLAConfig,
     ModelConfig,
     MoEConfig,
     RLConfig,
@@ -19,6 +20,7 @@ from repro.configs.base import (  # noqa: F401  (re-exported)
     ShapeConfig,
     SSMConfig,
     WMConfig,
+    YarnConfig,
     get_shape,
 )
 
@@ -29,6 +31,7 @@ from repro.configs.internlm2_1_8b import CONFIG as _internlm2
 from repro.configs.zamba2_1_2b import CONFIG as _zamba2
 from repro.configs.dbrx_132b import CONFIG as _dbrx
 from repro.configs.deepseek_7b import CONFIG as _deepseek
+from repro.configs.deepseek_v2_lite import CONFIG as _deepseek_v2_lite
 from repro.configs.musicgen_medium import CONFIG as _musicgen
 from repro.configs.llava_next_mistral_7b import CONFIG as _llava
 from repro.configs.mamba2_2_7b import CONFIG as _mamba2
@@ -44,6 +47,7 @@ _REGISTRY: Dict[str, ModelConfig] = {
         _zamba2,
         _dbrx,
         _deepseek,
+        _deepseek_v2_lite,
         _musicgen,
         _llava,
         _mamba2,
@@ -100,12 +104,17 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 256,
         head_dim_override=d_model // heads if heads else None,
     )
     if cfg.moe is not None:
-        # capacity_factor = E/k guarantees zero token drops, making the
-        # grouped dispatch exactly equal to per-token top-k routing — so the
-        # smoke tests can assert prefill/decode vs forward consistency.
         updates["moe"] = dataclasses.replace(
             cfg.moe, num_experts=4, top_k=2, d_ff=d_model,
-            capacity_factor=2.0)
+            num_shared_experts=min(cfg.moe.num_shared_experts, 2))
+        updates["first_k_dense"] = min(cfg.first_k_dense, layers - 1)
+    if cfg.mla is not None:
+        # heads of 32 (16 + 16 rotary) over a 64-wide latent, values 32
+        half = d_model // heads // 2
+        updates["mla"] = MLAConfig(kv_lora_rank=d_model // 4,
+                                   qk_nope_head_dim=half,
+                                   qk_rope_head_dim=half,
+                                   v_head_dim=2 * half)
     if cfg.ssm is not None:
         updates["ssm"] = dataclasses.replace(
             cfg.ssm, state_dim=16, head_dim=32, chunk=32)

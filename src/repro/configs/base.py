@@ -12,14 +12,52 @@ from typing import Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts settings (GShard-style capacity dispatch)."""
+    """Mixture-of-experts settings: a softmax router over all
+    ``num_experts``, the greedy top-k, and the share of the experts this
+    device holds (``held_first`` .. ``held_first + held - 1``; ``held`` 0
+    holds them all). No token is dropped."""
 
     num_experts: int
     top_k: int
     d_ff: int                       # per-expert hidden width
-    capacity_factor: float = 1.25
     router_z_coef: float = 1e-3
     load_balance_coef: float = 1e-2
+    norm_topk_prob: bool = True     # renormalise the top-k gates
+    num_shared_experts: int = 0     # one SwiGLU of num_shared * d_ff
+    held_first: int = 0
+    held: int = 0
+
+    @property
+    def num_held(self) -> int:
+        return self.held or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2) without a query LoRA:
+    keys and values come from a ``kv_lora_rank`` latent, and a
+    ``qk_rope_head_dim`` rotary part of the key is shared by all heads."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (``rope_scaling`` of type ``yarn``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,9 +121,12 @@ class ModelConfig:
     sliding_window: Optional[int] = None      # normal operation
     long_context_window: int = 8192           # long_500k fallback for dense
     head_dim_override: Optional[int] = None
+    rope_scaling: Optional[YarnConfig] = None
+    mla: Optional[MLAConfig] = None
 
     # -- optional sub-configs ---------------------------------------------------
     moe: Optional[MoEConfig] = None
+    first_k_dense: int = 0           # leading dense (d_ff) layers of a moe stack
     ssm: Optional[SSMConfig] = None
     hybrid: Optional[HybridConfig] = None
 
@@ -131,13 +172,15 @@ class ModelConfig:
                 + d                                    # pre-norm
             )
             return total + L * per
-        kvh = self.num_kv_heads
-        hd = self.head_dim
-        attn = d * self.num_heads * hd + 2 * d * kvh * hd + self.num_heads * hd * d
+        attn = self.attn_param_count()
         if self.arch_type == "moe":
             assert self.moe is not None
-            ff = 3 * d * self.moe.d_ff * self.moe.num_experts
-            ff += d * self.moe.num_experts             # router
+            m = self.moe
+            ff = 3 * d * m.d_ff * (m.num_held + m.num_shared_experts)
+            ff += d * m.num_experts                    # router
+            k = self.first_k_dense
+            total += k * (attn + 3 * d * self.d_ff + 2 * d)
+            L -= k
         else:
             ff = 3 * d * self.d_ff
         per = attn + ff + 2 * d                        # two norms
@@ -157,14 +200,30 @@ class ModelConfig:
             total += L * per + shared
         return total
 
+    def attn_param_count(self) -> int:
+        """Parameters of one attention block."""
+        d, h = self.d_model, self.num_heads
+        if self.mla is not None:
+            a = self.mla
+            return (d * h * a.qk_head_dim
+                    + d * (a.kv_lora_rank + a.qk_rope_head_dim)
+                    + a.kv_lora_rank
+                    + a.kv_lora_rank * h * (a.qk_nope_head_dim + a.v_head_dim)
+                    + h * a.v_head_dim * d)
+        hd = self.head_dim
+        return d * h * hd + 2 * d * self.num_kv_heads * hd + h * hd * d
+
     def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: only top-k experts)."""
+        """Parameters touched per token (MoE: only top-k of the held
+        experts)."""
         if self.arch_type != "moe":
             return self.param_count()
         assert self.moe is not None
-        d, L = self.d_model, self.num_layers
-        dense_share = self.param_count() - L * 3 * d * self.moe.d_ff * self.moe.num_experts
-        return dense_share + L * 3 * d * self.moe.d_ff * self.moe.top_k
+        m = self.moe
+        experts = (self.num_layers - self.first_k_dense) * 3 \
+            * self.d_model * m.d_ff
+        return (self.param_count() - experts * m.num_held
+                + experts * min(m.top_k, m.num_held))
 
 
 @dataclasses.dataclass(frozen=True)

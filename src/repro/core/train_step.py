@@ -155,7 +155,8 @@ def _assemble_loss(cfg: ModelConfig, rl: RLConfig, pg, v_loss, kl, ent,
     }
     if cfg.arch_type == "moe":
         metrics["moe_load_balance"] = aux["load_balance"]
-        metrics["moe_dropped_frac"] = aux["dropped_frac"]
+        metrics["moe_held_frac"] = aux["held_frac"]
+        metrics["moe_load_max"] = aux["load_max"]
     return total, (metrics, stats)
 
 

@@ -195,17 +195,21 @@ def policy_head_loss(hidden, w, targets, logp_old, advantages, mask, *,
 # Attention: Pallas flash forward + Pallas flash backward (LSE residual)
 # ---------------------------------------------------------------------------
 
-def _attn_pallas_ok(head_dim: int) -> bool:
-    """On a real TPU the flash kernel wants MXU-aligned head dims; the jnp
-    twin handles the rest. Interpret mode (CPU) takes any shape."""
-    if interpret_mode():
-        return True
-    return head_dim % 128 == 0
+def _attn_pallas_ok(q, v, scale) -> bool:
+    """The kernels take one head dim for q, k and v and the softmax scale
+    ``head_dim ** -0.5``; on a real TPU they want MXU-aligned head dims.
+    The jnp twin handles the rest (latent attention's 192-wide q/k heads
+    with 128-wide values and a YaRN-scaled scale). Interpret mode (CPU)
+    takes any aligned shape."""
+    if scale is not None or v.shape[-1] != q.shape[-1]:
+        return False
+    return interpret_mode() or q.shape[-1] % 128 == 0
 
 
-def _twin_attention(q, k, v, window, block, unroll=False):
+def _twin_attention(q, k, v, window, block, unroll=False, scale=None):
     from repro.models.attention import _blockwise_attn
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     return _blockwise_attn(q, k, v, scale, window=window, block=block,
                            unroll=unroll).astype(q.dtype)
 
@@ -318,32 +322,35 @@ def _flash(q, k, v, window, block):
 
 
 def attention(q, k, v, *, window: Optional[int] = None, block: int = 128,
-              unroll: bool = False, mode: Optional[str] = None):
+              unroll: bool = False, scale: Optional[float] = None,
+              mode: Optional[str] = None):
     """Causal (optionally sliding-window) blockwise attention on projected
-    q/k/v. q: [B,T,H,D]; k/v: [B,S,KV,D] -> [B,T,H,D] in q.dtype.
+    q/k/v. q/k: [B,T,H,D], [B,S,KV,D]; v: [B,S,KV,Dv] -> [B,T,H,Dv] in
+    q.dtype. ``scale`` defaults to ``D ** -0.5``.
 
     Routes to the Pallas flash kernel when enabled and shape-eligible;
     its backward is the pair of Pallas dq and dk/dv kernels over the
     saved online-softmax LSE (recompute-free — no O(T²) score tensor
     either way). Otherwise the jnp twin runs both ways.
     """
-    if use_pallas(mode) and _attn_pallas_ok(q.shape[-1]):
+    if use_pallas(mode) and _attn_pallas_ok(q, v, scale):
         return _flash(q, k, v, window, block)
-    return _twin_attention(q, k, v, window, block, unroll)
+    return _twin_attention(q, k, v, window, block, unroll, scale)
 
 
 # ---------------------------------------------------------------------------
 # Decode-path routing: single-token decode + the dense small-T fallback
 # ---------------------------------------------------------------------------
 
-def _twin_dense(q, k, v, window):
+def _twin_dense(q, k, v, window, scale=None):
     """Dense causal attention — the exact math of the historical inline
     small-T path in ``models.attention`` (f32 scores + additive causal
     mask + softmax cast to q.dtype before the value combine)."""
     from repro.models.attention import (_gqa_combine, _gqa_scores,
                                         causal_mask)
     t = q.shape[1]
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     scores = _gqa_scores(q, k) * scale
     scores = scores + causal_mask(t, window)[None, None]
     weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
@@ -351,42 +358,47 @@ def _twin_dense(q, k, v, window):
 
 
 def dense_attention(q, k, v, *, window: Optional[int] = None,
-                    block: int = 128, mode: Optional[str] = None):
+                    block: int = 128, scale: Optional[float] = None,
+                    mode: Optional[str] = None):
     """Dense small-T causal attention (T == S, no KV cache): the fallback
     the blockwise path skips when the whole sequence fits one block.
-    q: [B,T,H,D]; k/v: [B,T,KV,D] -> [B,T,H,D] in q.dtype.
+    q/k: [B,T,H,D], [B,T,KV,D]; v: [B,T,KV,Dv] -> [B,T,H,Dv] in q.dtype;
+    ``scale`` defaults to ``D ** -0.5``.
 
     Pallas route: the flash kernel (it pads T up to one block tile
     internally, so a 17-token prompt still runs as a single MXU tile);
     differentiable through the twin-VJP wrapper like ``attention``.
     """
-    if use_pallas(mode) and _attn_pallas_ok(q.shape[-1]):
+    if use_pallas(mode) and _attn_pallas_ok(q, v, scale):
         return _flash(q, k, v, window, block)
-    return _twin_dense(q, k, v, window)
+    return _twin_dense(q, k, v, window, scale)
 
 
-def _twin_decode(q, k, v, valid):
+def _twin_decode(q, k, v, valid, scale=None):
     """Single-token decode over a (possibly ring-layout) KV cache — the
     exact math of the historical inline path in ``attention_decode``."""
     from repro.models.attention import NEG_INF, _gqa_combine, _gqa_scores
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     scores = _gqa_scores(q, k) * scale                    # [B,H,1,S]
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return _gqa_combine(weights, v)
 
 
-def decode_attention(q, k, v, valid, *, mode: Optional[str] = None):
-    """Single-token attention decode. q: [B,1,H,D]; k/v: [B,S,KV,D];
-    valid: [B,S] bool (cache slots this token may attend to — empty ring
-    slots, out-of-window and future positions already excluded)
-    -> [B,1,H,D] in q.dtype.
+def decode_attention(q, k, v, valid, *, scale: Optional[float] = None,
+                     mode: Optional[str] = None):
+    """Single-token attention decode. q: [B,1,H,D]; k: [B,S,KV,D]; v:
+    [B,S,KV,Dv]; ``scale`` defaults to ``D ** -0.5``; valid: [B,S] bool
+    (cache slots this token may attend to — empty ring slots,
+    out-of-window and future positions already excluded)
+    -> [B,1,H,Dv] in q.dtype.
 
     Validity is data-dependent (ring caches overwrite slots out of
     order), so the Pallas route carries it as an additive bias instead of
     deriving a mask from grid positions. Inference-only — no VJP wrapper.
     """
-    if use_pallas(mode) and _attn_pallas_ok(q.shape[-1]):
+    if use_pallas(mode) and _attn_pallas_ok(q, v, scale):
         from repro.kernels.decode_attention import (
             decode_attention as _pallas_decode)
         from repro.models.attention import NEG_INF
@@ -395,4 +407,35 @@ def decode_attention(q, k, v, valid, *, mode: Optional[str] = None):
         return _per_device(
             lambda *a: _pallas_decode(*a, interpret=interpret),
             q, k, v, bias)
-    return _twin_decode(q, k, v, valid)
+    return _twin_decode(q, k, v, valid, scale)
+
+
+# ---------------------------------------------------------------------------
+# Grouped matmul (the expert layer): Pallas gmm / tgmm under a custom VJP
+# ---------------------------------------------------------------------------
+
+def _gmm_pallas_ok(lhs, rhs) -> bool:
+    """On a real TPU the kernels tile the contracted and output widths in
+    whole 128-lane tiles. Interpret mode takes any shape."""
+    return interpret_mode() or (lhs.shape[1] % 128 == 0
+                                and rhs.shape[2] % 128 == 0)
+
+
+def grouped_matmul(lhs, rhs, sizes, *, mode: Optional[str] = None):
+    """``lhs[rows of i] @ rhs[i]`` for the row-sorted groups of ``sizes``
+    (the rows past ``sum(sizes)`` read zeros). lhs: [m, k]; rhs: [g, k, n];
+    sizes: [g] int32 -> [m, n] in lhs.dtype.
+
+    Pallas route: ``kernels/grouped_matmul.py``, whose grid visits only
+    the row tiles that hold a group's rows, with ``gmm`` for the input
+    gradient and ``tgmm`` for the weight gradient. The twin is
+    ``lax.ragged_dot``, differentiated by JAX.
+    """
+    if use_pallas(mode) and _gmm_pallas_ok(lhs, rhs):
+        from repro.kernels.grouped_matmul import grouped_matmul as _pallas
+        interpret = interpret_mode()
+        return _per_device(lambda *a: _pallas(*a, interpret), lhs, rhs,
+                           sizes)
+    return jax.lax.ragged_dot(lhs, rhs, sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(lhs.dtype)

@@ -149,7 +149,7 @@ def _blockwise_attn(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     init = (jnp.full((b, h, t), NEG_INF, jnp.float32),
             jnp.zeros((b, h, t), jnp.float32),
-            jnp.zeros((b, h, t, d), jnp.float32))
+            jnp.zeros((b, h, t, v.shape[-1]), jnp.float32))
     (m, l, acc), _ = jax.lax.scan(jax.checkpoint(body), init, jnp.arange(nb),
                                   unroll=nb if unroll else 1)
     out = acc / jnp.maximum(l, 1e-30)[..., None]        # [B,H,T,D]
@@ -183,24 +183,66 @@ def attention_prefill(params: Params, x: jnp.ndarray, *, rope_theta: float,
         out = dispatch.dense_attention(q, k, v, window=window)
     out = jnp.einsum("bthk,hkd->btd", out, params["wo"])
 
+    k_c, v_c, pos_c = cache_layout((k, v), positions, cache_len)
+    cache = KVCache(k=k_c, v=v_c, positions=pos_c,
+                    length=jnp.full((b,), t, dtype=jnp.int32))
+    return out, cache
+
+
+def cache_layout(xs, positions: jnp.ndarray, cache_len: int):
+    """Prompt arrays ``xs`` ([B, T, ...] each) and their positions
+    ([1, T]) laid out in a ``cache_len``-slot cache: padded with empty
+    slots, or, when the cache is shorter, the last ``cache_len`` tokens
+    in ring order (slot = position % cache_len). Returns (*xs, positions
+    [B, S] int32)."""
+    b, t = xs[0].shape[:2]
     if cache_len >= t:
         pad = cache_len - t
-        k_c = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_c = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        out = [jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+               for x in xs]
         pos_c = jnp.pad(jnp.broadcast_to(positions, (b, t)),
                         ((0, 0), (0, pad)), constant_values=-1)
     else:  # ring buffer keeps the last ``cache_len`` tokens
-        k_c = k[:, t - cache_len:]
-        v_c = v[:, t - cache_len:]
+        out = [x[:, t - cache_len:] for x in xs]
         pos_c = jnp.broadcast_to(positions[:, t - cache_len:], (b, cache_len))
         # ring layout: slot = pos % cache_len
         slots = pos_c[0] % cache_len
         inv = jnp.argsort(slots)
-        k_c, v_c = k_c[:, inv], v_c[:, inv]
+        out = [x[:, inv] for x in out]
         pos_c = pos_c[:, inv]
-    cache = KVCache(k=k_c, v=v_c, positions=pos_c.astype(jnp.int32),
-                    length=jnp.full((b,), t, dtype=jnp.int32))
-    return out, cache
+    return (*out, pos_c.astype(jnp.int32))
+
+
+def cache_write(cached, new, positions: jnp.ndarray, pos: jnp.ndarray,
+                uniform: bool):
+    """Write one decoded token's arrays ``new`` ([B, 1, ...] each) into the
+    ``cached`` arrays ([B, S, ...]) at slot ``pos % S``. Returns (*cached,
+    positions). ``uniform``: every row is at the same position, so the
+    write is one dynamic-update-slice at a scalar slot."""
+    b = pos.shape[0]
+    slot = (pos % cached[0].shape[1]).astype(jnp.int32)
+    if uniform:
+        s0 = slot[0]
+        out = [jax.lax.dynamic_update_slice_in_dim(c, x.astype(c.dtype), s0,
+                                                   axis=1)
+               for c, x in zip(cached, new)]
+        pos_c = jax.lax.dynamic_update_slice(
+            positions, pos[:, None], (jnp.int32(0), s0))
+    else:
+        b_idx = jnp.arange(b)
+        out = [c.at[b_idx, slot].set(x[:, 0].astype(c.dtype))
+               for c, x in zip(cached, new)]
+        pos_c = positions.at[b_idx, slot].set(pos)
+    return (*out, pos_c)
+
+
+def decode_valid(pos_c: jnp.ndarray, pos: jnp.ndarray,
+                 window: Optional[int]) -> jnp.ndarray:
+    """[B, S] cache slots the token at ``pos`` may attend to."""
+    valid = pos_c >= 0
+    if window is not None:
+        valid &= (pos[:, None] - pos_c) < window
+    return valid & (pos_c <= pos[:, None])
 
 
 def attention_decode(params: Params, x: jnp.ndarray, cache: KVCache, *,
@@ -215,8 +257,6 @@ def attention_decode(params: Params, x: jnp.ndarray, cache: KVCache, *,
     dynamic-update-slice at a scalar slot instead of a batched scatter —
     GSPMD keeps the batch-sharded cache in place (a scatter with per-row
     indices forces replication)."""
-    b = x.shape[0]
-    cache_len = cache.k.shape[1]
     pos = cache.length                                     # [B]
     q = jnp.einsum("btd,dhk->bthk", x, params["wq"])
     k_new = jnp.einsum("btd,dhk->bthk", x, params["wk"])
@@ -224,25 +264,9 @@ def attention_decode(params: Params, x: jnp.ndarray, cache: KVCache, *,
     q = apply_rope(q, pos[:, None], rope_theta)
     k_new = apply_rope(k_new, pos[:, None], rope_theta)
 
-    slot = (pos % cache_len).astype(jnp.int32)   # ring layout (== pos when S_cache > pos)
-    if uniform:
-        s0 = slot[0]
-        k_c = jax.lax.dynamic_update_slice_in_dim(
-            cache.k, k_new.astype(cache.k.dtype), s0, axis=1)
-        v_c = jax.lax.dynamic_update_slice_in_dim(
-            cache.v, v_new.astype(cache.v.dtype), s0, axis=1)
-        pos_c = jax.lax.dynamic_update_slice(
-            cache.positions, pos[:, None], (jnp.int32(0), s0))
-    else:
-        b_idx = jnp.arange(b)
-        k_c = cache.k.at[b_idx, slot].set(k_new[:, 0].astype(cache.k.dtype))
-        v_c = cache.v.at[b_idx, slot].set(v_new[:, 0].astype(cache.v.dtype))
-        pos_c = cache.positions.at[b_idx, slot].set(pos)
-
-    valid = pos_c >= 0                                        # [B,S]
-    if window is not None:
-        valid &= (pos[:, None] - pos_c) < window
-    valid &= pos_c <= pos[:, None]
+    k_c, v_c, pos_c = cache_write((cache.k, cache.v), (k_new, v_new),
+                                  cache.positions, pos, uniform)
+    valid = decode_valid(pos_c, pos, window)
     out = dispatch.decode_attention(q, k_c, v_c, valid)
     out = jnp.einsum("bthk,hkd->btd", out, params["wo"])
     new_cache = KVCache(k=k_c, v=v_c, positions=pos_c, length=pos + 1)

@@ -6,10 +6,14 @@ dependency, so the same code paths run under jit, shard_map, and
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+import math
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from repro.configs.base import YarnConfig
 
 
 Params = Dict[str, jnp.ndarray]
@@ -47,18 +51,50 @@ def rmsnorm(params: Params, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
 # Rotary position embedding
 # --------------------------------------------------------------------------
 
-def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor, ``0.1 m ln s + 1``."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_correction_dim(rotations: float, dim: int, theta: float,
+                         max_pos: int) -> float:
+    return (dim * math.log(max_pos / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
+def rope_frequencies(head_dim: int, theta: float,
+                     yarn: Optional[YarnConfig] = None) -> jnp.ndarray:
+    """Inverse frequencies of the ``head_dim // 2`` rotary pairs. With
+    ``yarn``, the pairs between the correction dims of ``beta_fast`` and
+    ``beta_slow`` blend from the original frequency to it over
+    ``factor`` along a linear ramp (DeepSeek-V2's YaRN)."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if yarn is None:
+        return 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    extra = 1.0 / (theta ** (np.arange(half, dtype=np.float64) / half))
+    orig = yarn.original_max_position_embeddings
+    low = max(math.floor(_yarn_correction_dim(
+        yarn.beta_fast, head_dim, theta, orig)), 0)
+    high = min(math.ceil(_yarn_correction_dim(
+        yarn.beta_slow, head_dim, theta, orig)), head_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return jnp.asarray(extra / yarn.factor * ramp + extra * (1.0 - ramp),
+                       jnp.float32)
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float) -> jnp.ndarray:
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               yarn: Optional[YarnConfig] = None) -> jnp.ndarray:
     """x: [..., T, H, D]; positions: broadcastable to [..., T]."""
-    freqs = rope_frequencies(x.shape[-1], theta)          # [D/2]
+    freqs = rope_frequencies(x.shape[-1], theta, yarn)    # [D/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., T, D/2]
     angles = angles[..., None, :]                         # [..., T, 1, D/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        ratio = (yarn_mscale(yarn.factor, yarn.mscale)
+                 / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * ratio, sin * ratio
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

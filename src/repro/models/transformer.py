@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn_lib
+from repro.models import mla as mla_lib
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 from repro.models.attention import KVCache
@@ -47,23 +48,30 @@ FRONTEND_DIM = 1024  # stub modality-frontend embedding width (ViT/EnCodec)
 
 
 class DecodeCache(NamedTuple):
-    """Family-polymorphic decode cache."""
+    """Family-polymorphic decode cache. Attention caches are ``KVCache``s,
+    or ``mla.LatentCache``s for latent attention."""
 
     attn: Optional[KVCache]      # stacked [L or n_shared, ...] or None
     ssm: Optional[SSMState]      # stacked [L, ...] or None
+    dense: Optional[KVCache] = None  # a moe stack's leading dense layers
 
 
 # ---------------------------------------------------------------------------
 # Parameter initialization
 # ---------------------------------------------------------------------------
 
+def _attn_init(key, cfg: ModelConfig, dtype) -> Params:
+    if cfg.mla is not None:
+        return mla_lib.mla_init(key, cfg, dtype)
+    return attn_lib.attention_init(key, cfg.d_model, cfg.num_heads,
+                                   cfg.num_kv_heads, cfg.head_dim, dtype)
+
+
 def _attn_block_init(key, cfg: ModelConfig, d_ff: int, dtype) -> Params:
     k1, k2 = jax.random.split(key)
     return {
         "attn_norm": rmsnorm_init(cfg.d_model, dtype),
-        "attn": attn_lib.attention_init(
-            k1, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
-            cfg.head_dim, dtype),
+        "attn": _attn_init(k1, cfg, dtype),
         "mlp_norm": rmsnorm_init(cfg.d_model, dtype),
         "mlp": mlp_init(k2, cfg.d_model, d_ff, dtype),
     }
@@ -73,9 +81,7 @@ def _moe_block_init(key, cfg: ModelConfig, dtype) -> Params:
     k1, k2 = jax.random.split(key)
     return {
         "attn_norm": rmsnorm_init(cfg.d_model, dtype),
-        "attn": attn_lib.attention_init(
-            k1, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
-            cfg.head_dim, dtype),
+        "attn": _attn_init(k1, cfg, dtype),
         "mlp_norm": rmsnorm_init(cfg.d_model, dtype),
         "moe": moe_lib.moe_init(k2, cfg.d_model, cfg.moe, dtype),
     }
@@ -125,8 +131,13 @@ def init_params(cfg: ModelConfig, key) -> Params:
             lambda k: _attn_block_init(k, cfg, cfg.d_ff, dtype),
             ks[3], cfg.num_layers)
     elif cfg.arch_type == "moe":
+        if cfg.first_k_dense:
+            params["layers_dense"] = _stacked_init(
+                lambda k: _attn_block_init(k, cfg, cfg.d_ff, dtype), ks[6],
+                cfg.first_k_dense)
         params["layers"] = _stacked_init(
-            lambda k: _moe_block_init(k, cfg, dtype), ks[3], cfg.num_layers)
+            lambda k: _moe_block_init(k, cfg, dtype), ks[3],
+            cfg.num_layers - cfg.first_k_dense)
     elif cfg.arch_type == "ssm":
         params["layers"] = _stacked_init(
             lambda k: _ssm_block_init(k, cfg, dtype), ks[3], cfg.num_layers)
@@ -161,12 +172,19 @@ def embed_inputs(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
 # Blocks (single layer, unstacked params)
 # ---------------------------------------------------------------------------
 
+def _attn_forward(p: Params, h, cfg: ModelConfig, window, block, unroll):
+    if cfg.mla is not None:
+        return mla_lib.mla_forward(p, h, cfg, window=window, block=block,
+                                   unroll=unroll)
+    return attn_lib.attention_forward(
+        p, h, rope_theta=cfg.rope_theta, window=window, block=block,
+        unroll=unroll)
+
+
 def _attn_block_forward(p: Params, x, cfg: ModelConfig, window,
                         block=None, unroll=False):
     h = rmsnorm(p["attn_norm"], x, cfg.norm_eps)
-    x = x + attn_lib.attention_forward(
-        p["attn"], h, rope_theta=cfg.rope_theta, window=window, block=block,
-        unroll=unroll)
+    x = x + _attn_forward(p["attn"], h, cfg, window, block, unroll)
     h = rmsnorm(p["mlp_norm"], x, cfg.norm_eps)
     return x + mlp(p["mlp"], h)
 
@@ -174,9 +192,7 @@ def _attn_block_forward(p: Params, x, cfg: ModelConfig, window,
 def _moe_block_forward(p: Params, x, cfg: ModelConfig, window, block=None,
                        unroll=False):
     h = rmsnorm(p["attn_norm"], x, cfg.norm_eps)
-    x = x + attn_lib.attention_forward(
-        p["attn"], h, rope_theta=cfg.rope_theta, window=window, block=block,
-        unroll=unroll)
+    x = x + _attn_forward(p["attn"], h, cfg, window, block, unroll)
     h = rmsnorm(p["mlp_norm"], x, cfg.norm_eps)
     out, aux = moe_lib.moe_forward(p["moe"], h, cfg.moe)
     return x + out, aux
@@ -187,7 +203,9 @@ def _ssm_block_forward(p: Params, x, cfg: ModelConfig):
     return x + ssm_lib.ssm_forward(p["ssm"], h, cfg.d_model, cfg.ssm)
 
 
-_ZERO_AUX = {"load_balance": 0.0, "router_z": 0.0, "dropped_frac": 0.0}
+_ZERO_AUX = {"load_balance": 0.0, "router_z": 0.0}
+# per-layer readings of the expert layer, averaged (not summed) over layers
+_MEAN_AUX = ("held_frac", "load_max")
 
 
 # ---------------------------------------------------------------------------
@@ -223,18 +241,23 @@ def forward(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
 
     x = _pin(x)
 
-    if cfg.arch_type in ("dense", "audio", "vlm"):
-        ur = cfg.num_layers if unroll else 1
+    def dense_stack(x, layers):
+        ur = jax.tree.leaves(layers)[0].shape[0] if unroll else 1
 
         def body(carry, layer_p):
             return _pin(_attn_block_forward(layer_p, carry, cfg, window,
                                             block, unroll)), None
         if remat:
             body = jax.checkpoint(body)
-        x, _ = jax.lax.scan(body, x, params["layers"], unroll=ur)
+        return jax.lax.scan(body, x, layers, unroll=ur)[0]
+
+    if cfg.arch_type in ("dense", "audio", "vlm"):
+        x = dense_stack(x, params["layers"])
         aux = dict(_ZERO_AUX)
     elif cfg.arch_type == "moe":
-        ur = cfg.num_layers if unroll else 1
+        if cfg.first_k_dense:
+            x = dense_stack(x, params["layers_dense"])
+        ur = cfg.num_layers - cfg.first_k_dense if unroll else 1
 
         def body(carry, layer_p):
             out, aux = _moe_block_forward(layer_p, carry, cfg, window, block,
@@ -243,8 +266,8 @@ def forward(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
         if remat:
             body = jax.checkpoint(body)
         x, auxs = jax.lax.scan(body, x, params["layers"], unroll=ur)
-        aux = jax.tree.map(jnp.sum, auxs)
-        aux["dropped_frac"] = aux["dropped_frac"] / cfg.num_layers
+        aux = {k: (jnp.mean if k in _MEAN_AUX else jnp.sum)(v)
+               for k, v in auxs.items()}
     elif cfg.arch_type == "ssm":
         ur = cfg.num_layers if unroll else 1
 
@@ -295,11 +318,23 @@ def init_decode_cache(cfg: ModelConfig, batch: int, cache_len: int, *,
     eff_len = min(cache_len, window) if window else cache_len
     attn_cache = None
     ssm_cache = None
-    if cfg.arch_type in ("dense", "audio", "vlm", "moe"):
-        def one(_):
-            return attn_lib.init_cache(batch, eff_len, cfg.num_kv_heads,
-                                       cfg.head_dim, dtype)
-        attn_cache = jax.vmap(one)(jnp.arange(cfg.num_layers))
+    dense_cache = None
+
+    def attn_caches(n):
+        if cfg.mla is not None:
+            one = lambda _: mla_lib.init_cache(batch, eff_len, cfg,  # noqa
+                                               dtype)
+        else:
+            one = lambda _: attn_lib.init_cache(  # noqa: E731
+                batch, eff_len, cfg.num_kv_heads, cfg.head_dim, dtype)
+        return jax.vmap(one)(jnp.arange(n))
+
+    if cfg.arch_type in ("dense", "audio", "vlm"):
+        attn_cache = attn_caches(cfg.num_layers)
+    elif cfg.arch_type == "moe":
+        attn_cache = attn_caches(cfg.num_layers - cfg.first_k_dense)
+        if cfg.first_k_dense:
+            dense_cache = attn_caches(cfg.first_k_dense)
     elif cfg.arch_type == "ssm":
         def one(_):
             return ssm_lib.init_ssm_state(batch, cfg.d_model, cfg.ssm, dtype)
@@ -315,7 +350,7 @@ def init_decode_cache(cfg: ModelConfig, batch: int, cache_len: int, *,
         def one_s(_):
             return ssm_lib.init_ssm_state(batch, cfg.d_model, cfg.ssm, dtype)
         ssm_cache = jax.vmap(one_s)(jnp.arange(cfg.num_layers))
-    return DecodeCache(attn=attn_cache, ssm=ssm_cache)
+    return DecodeCache(attn=attn_cache, ssm=ssm_cache, dense=dense_cache)
 
 
 # ---------------------------------------------------------------------------
@@ -336,23 +371,34 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
 
     def attn_sub(p, h):
         hn = rmsnorm(p["attn_norm"], h, cfg.norm_eps)
-        out, cache = attn_lib.attention_prefill(
-            p["attn"], hn, rope_theta=cfg.rope_theta, cache_len=eff_len,
-            window=window, block=block, unroll=unroll)
+        if cfg.mla is not None:
+            out, cache = mla_lib.mla_prefill(
+                p["attn"], hn, cfg, cache_len=eff_len, window=window,
+                block=block, unroll=unroll)
+        else:
+            out, cache = attn_lib.attention_prefill(
+                p["attn"], hn, rope_theta=cfg.rope_theta, cache_len=eff_len,
+                window=window, block=block, unroll=unroll)
         return h + out, cache
 
-    if cfg.arch_type in ("dense", "audio", "vlm", "moe"):
+    def stack(x, layers, moe: bool):
         def body(carry, layer_p):
             h, cache = attn_sub(layer_p, carry)
             hn = rmsnorm(layer_p["mlp_norm"], h, cfg.norm_eps)
-            if cfg.arch_type == "moe":
+            if moe:
                 out, _ = moe_lib.moe_forward(layer_p["moe"], hn, cfg.moe)
             else:
                 out = mlp(layer_p["mlp"], hn)
             return h + out, cache
-        x, attn_cache = jax.lax.scan(body, x, params["layers"],
-                                     unroll=cfg.num_layers if unroll else 1)
-        cache = DecodeCache(attn=attn_cache, ssm=None)
+        n = jax.tree.leaves(layers)[0].shape[0]
+        return jax.lax.scan(body, x, layers, unroll=n if unroll else 1)
+
+    if cfg.arch_type in ("dense", "audio", "vlm", "moe"):
+        dense_cache = None
+        if cfg.first_k_dense:
+            x, dense_cache = stack(x, params["layers_dense"], False)
+        x, attn_cache = stack(x, params["layers"], cfg.arch_type == "moe")
+        cache = DecodeCache(attn=attn_cache, ssm=None, dense=dense_cache)
     elif cfg.arch_type == "ssm":
         def body(carry, layer_p):
             hn = rmsnorm(layer_p["norm"], carry, cfg.norm_eps)
@@ -422,24 +468,37 @@ def decode(cfg: ModelConfig, params: Params, token: jnp.ndarray,
 
     def attn_sub(p, h, kv):
         hn = rmsnorm(p["attn_norm"], h, cfg.norm_eps)
-        out, kv = attn_lib.attention_decode(
-            p["attn"], hn, kv, rope_theta=cfg.rope_theta, window=window,
-            uniform=uniform)
+        if cfg.mla is not None:
+            out, kv = mla_lib.mla_decode(p["attn"], hn, kv, cfg,
+                                         window=window, uniform=uniform)
+        else:
+            out, kv = attn_lib.attention_decode(
+                p["attn"], hn, kv, rope_theta=cfg.rope_theta, window=window,
+                uniform=uniform)
         return h + out, kv
 
-    if cfg.arch_type in ("dense", "audio", "vlm", "moe"):
+    def stack(x, layers, kvs, moe: bool):
         def body(carry, scanned):
             layer_p, kv = scanned
             h, kv = attn_sub(layer_p, carry, kv)
             hn = rmsnorm(layer_p["mlp_norm"], h, cfg.norm_eps)
-            if cfg.arch_type == "moe":
+            if moe:
                 out, _ = moe_lib.moe_forward(layer_p["moe"], hn, cfg.moe)
             else:
                 out = mlp(layer_p["mlp"], hn)
             return h + out, kv
-        x, attn_cache = jax.lax.scan(body, x, (params["layers"], cache.attn),
-                                     unroll=cfg.num_layers if unroll else 1)
-        new_cache = DecodeCache(attn=attn_cache, ssm=None)
+        n = jax.tree.leaves(layers)[0].shape[0]
+        return jax.lax.scan(body, x, (layers, kvs),
+                            unroll=n if unroll else 1)
+
+    if cfg.arch_type in ("dense", "audio", "vlm", "moe"):
+        dense_cache = None
+        if cfg.first_k_dense:
+            x, dense_cache = stack(x, params["layers_dense"], cache.dense,
+                                   False)
+        x, attn_cache = stack(x, params["layers"], cache.attn,
+                              cfg.arch_type == "moe")
+        new_cache = DecodeCache(attn=attn_cache, ssm=None, dense=dense_cache)
     elif cfg.arch_type == "ssm":
         def body(carry, scanned):
             layer_p, st = scanned

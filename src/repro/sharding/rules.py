@@ -88,6 +88,10 @@ _RULES: List[Tuple[str, List[Candidate], List[Candidate]]] = [
     (r"attn/wk$", [(1, "model"), (2, "model"), (0, "model")], [(0, "data")]),
     (r"attn/wv$", [(1, "model"), (2, "model"), (0, "model")], [(0, "data")]),
     (r"attn/wo$", [(0, "model"), (1, "model"), (2, "model")], [(2, "data")]),
+    # latent attention: the latent projection is replicated, its per-head
+    # expansion takes the heads
+    (r"attn/w_kva$", [], [(0, "data")]),
+    (r"attn/w_kvb$", [(1, "model"), (2, "model")], [(0, "data")]),
     # dense MLP: d_ff on model
     (r"mlp/w_gate$", [(1, "model")], [(0, "data")]),
     (r"mlp/w_up$", [(1, "model")], [(0, "data")]),
@@ -97,6 +101,9 @@ _RULES: List[Tuple[str, List[Candidate], List[Candidate]]] = [
     (r"moe/w_gate$", [(0, "model")], [(2, "data")]),
     (r"moe/w_up$", [(0, "model")], [(2, "data")]),
     (r"moe/w_down$", [(0, "model")], [(1, "data")]),
+    (r"moe/shared/w_gate$", [(1, "model")], [(0, "data")]),
+    (r"moe/shared/w_up$", [(1, "model")], [(0, "data")]),
+    (r"moe/shared/w_down$", [(0, "model")], [(1, "data")]),
     # Mamba2 / SSD
     (r"ssm/in_proj$", [(1, "model")], [(0, "data")]),
     (r"ssm/in_proj_z$", [(1, "model")], [(0, "data")]),
@@ -142,8 +149,9 @@ def _leaf_path(path) -> str:
 
 
 def _is_stacked(path: str) -> bool:
-    return path.startswith(("layers/", "layers_rem/")) \
-        or "/layers/" in path or "/layers_rem/" in path
+    return path.startswith(("layers/", "layers_rem/", "layers_dense/")) \
+        or "/layers/" in path or "/layers_rem/" in path \
+        or "/layers_dense/" in path
 
 
 def param_specs(cfg: ModelConfig, param_shapes, mesh: Mesh,

@@ -26,6 +26,7 @@ from repro.kernels import gipo_loss as gl
 from repro.kernels.decode_attention import decode_attention
 
 fa = importlib.import_module("repro.kernels.flash_attention")
+gm = importlib.import_module("repro.kernels.grouped_matmul")
 ssd = importlib.import_module("repro.kernels.ssd_scan")
 
 DEEPSEEK = get_config("deepseek-7b")
@@ -134,6 +135,26 @@ def test_ssd_scan_value_and_grad_mamba2_widths(shape):
             shape((1, t, s.state_dim)))
     _compile(lambda *a: ssd.ssd_scan(*a, chunk=s.chunk), *args)
     _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), *args)
+
+
+@pytest.mark.parametrize("rows", [34560, 192])
+def test_grouped_matmul_value_and_grad_v2_lite_widths(shape, rows):
+    """``_gmm_kernel`` and ``_tgmm_kernel`` at DeepSeek-V2-Lite's expert
+    widths over 8 held experts: the train cell's row buffer (5,760 tokens
+    of a micro-batch x top 6; about 540 rows per held expert) and a decode
+    batch (32 tokens x top 6)."""
+    cfg = get_config("deepseek-v2-lite")
+    d, ff = cfg.d_model, cfg.moe.d_ff
+
+    def loss(x, w_in, w_out, sizes):
+        h = gm.grouped_matmul(x, w_in, sizes, False)
+        return gm.grouped_matmul(h, w_out, sizes, False).astype(
+            jnp.float32).sum()
+    compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                        shape((rows, d)), shape((8, d, ff)),
+                        shape((8, ff, d)), shape((8,), jnp.int32))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 5     # 2 fwd, 2 dlhs, 2 tgmm
 
 
 def test_smoke_train_step_fits_one_chip(shape, monkeypatch):
