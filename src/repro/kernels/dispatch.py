@@ -365,9 +365,12 @@ def dense_attention(q, k, v, *, window: Optional[int] = None,
     q/k: [B,T,H,D], [B,T,KV,D]; v: [B,T,KV,Dv] -> [B,T,H,Dv] in q.dtype;
     ``scale`` defaults to ``D ** -0.5``.
 
-    Pallas route: the flash kernel (it pads T up to one block tile
-    internally, so a 17-token prompt still runs as a single MXU tile);
-    differentiable through the twin-VJP wrapper like ``attention``.
+    Pallas route: the flash kernel, differentiable through the twin-VJP
+    wrapper like ``attention``. It packs short rows end to end into one
+    block tile under a block-diagonal mask, ``min(B, block // T)`` rows a
+    tile where T is at most half a block (``rows_per_tile``, from the
+    shapes alone): six 20-token rows fill 120 of a tile's 128 rows, where
+    a lone row padded to a tile filled 20. Longer rows take a tile each.
     """
     if use_pallas(mode) and _attn_pallas_ok(q, v, scale):
         return _flash(q, k, v, window, block)

@@ -17,7 +17,15 @@ flash-attention algorithm:
     ``[rows, 128]`` tiles — a lone ``[rows]`` vector has no TPU tile;
   * GQA is handled by mapping each q-head grid index to its kv head
     (h // group) in the K/V index maps — no KV duplication in HBM;
-  * causal + sliding-window masking from absolute positions.
+  * causal + sliding-window masking from absolute positions;
+  * short rows are packed: for self-attention (S == T) with T at most half
+    a q block, ``rows_per_tile`` sequences lie end to end in one tile —
+    ``P = min(B, block_q // T)``, from the shapes alone — and the mask
+    adds a block-diagonal term (a query sees the keys of its own sequence
+    only). Causality and the window hold within each sequence, both
+    positions carrying the same offset. The grid and the padded copies
+    shrink about P-fold; at T = 20 six rows fill 120 of 128 tile rows,
+    where one row filled 20. At P = 1 the wrapper runs as unpacked.
 
 The BACKWARD is a pair of real Pallas kernels too (no twin recompute):
 the forward optionally saves the per-row log-sum-exp (``return_lse``), and
@@ -71,8 +79,18 @@ def _softmax_scratch(rows: int, d: int):
             pltpu.VMEM((rows, d), jnp.float32)]        # accumulator
 
 
-def _mask(qi, kj, block_q, block_k, seq_k, causal, window):
-    """[block_q, block_k] validity from absolute positions."""
+def _segment(pos, seg_len: int):
+    """floor(pos / seg_len): which packed sequence a tile position is in.
+    Computed in f32 on the VPU, and exact: (pos + ½) / seg_len lies at
+    least ½ / seg_len from a whole number, far beyond f32 rounding at tile
+    positions."""
+    return jnp.floor((pos.astype(jnp.float32) + 0.5) * (1.0 / seg_len))
+
+
+def _mask(qi, kj, block_q, block_k, seq_k, causal, window, seg_len):
+    """[block_q, block_k] validity from absolute positions. With
+    ``seg_len`` the rows hold sequences of that length packed end to end,
+    and a query sees only the keys of its own sequence."""
     shape = (block_q, block_k)
     qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     kpos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
@@ -81,12 +99,20 @@ def _mask(qi, kj, block_q, block_k, seq_k, causal, window):
         mask &= kpos <= qpos
     if window is not None:
         mask &= (qpos - kpos) < window
+    if seg_len is not None:
+        # a column and a row of positions, broadcast in the compare
+        qseg = _segment(qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0), seg_len)
+        kseg = _segment(kj * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1), seg_len)
+        mask &= qseg == kseg
     return mask
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
                  scale: float, block_q: int, block_k: int, seq_k: int,
-                 causal: bool, window: Optional[int], save_lse: bool):
+                 causal: bool, window: Optional[int],
+                 seg_len: Optional[int], save_lse: bool):
     if save_lse:
         lse_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -103,7 +129,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
 
     s = jax.lax.dot_general(q_ref[...], k_ref[...], _NT,
                             preferred_element_type=jnp.float32) * scale
-    mask = _mask(qi, kj, block_q, block_k, seq_k, causal, window)
+    mask = _mask(qi, kj, block_q, block_k, seq_k, causal, window, seg_len)
     _softmax_step(jnp.where(mask, s, NEG_INF), v_ref[...], m_scr, l_scr,
                   acc_scr)
 
@@ -126,6 +152,31 @@ def _heads_major(x, seq_pad: int):
     return x
 
 
+def rows_per_tile(b: int, t: int, s: int, block_q: int) -> int:
+    """How many sequences share one q tile. Self-attention rows (S == T)
+    of at most half a tile are packed end to end, as many as fit and the
+    batch holds; longer rows and cross lengths take a tile each."""
+    if s != t or 2 * t > block_q:
+        return 1
+    return min(b, block_q // t)
+
+
+def _pack(x, p: int, pad_value: float = 0.0):
+    """[B, T, ...] -> [ceil(B / p), p·T, ...]: p sequences end to end in
+    each row, the batch padded with rows of ``pad_value``."""
+    b, t = x.shape[:2]
+    nb = -(-b // p)
+    if nb * p != b:
+        x = jnp.pad(x, ((0, nb * p - b),) + ((0, 0),) * (x.ndim - 1),
+                    constant_values=pad_value)
+    return x.reshape((nb, p * t) + x.shape[2:])
+
+
+def _unpack(x, b: int, t: int):
+    """Inverse of ``_pack``: [B / p, p·T, ...] -> [B, T, ...]."""
+    return x.reshape((-1, t) + x.shape[2:])[:b]
+
+
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: Optional[int] = None,
                     block_q: int = 128, block_k: int = 128,
@@ -133,10 +184,16 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     """q: [B, T, H, D]; k/v: [B, S, KV, D] with H % KV == 0 → [B, T, H, D].
 
     T and S are padded to block multiples internally; the causal mask uses
-    unpadded absolute positions, and key padding is masked out.
+    unpadded absolute positions, and key padding is masked out. Short
+    self-attention rows are packed ``rows_per_tile`` to a tile under a
+    block-diagonal mask (module docstring).
     ``return_lse`` additionally returns the per-row log-sum-exp
     [B, H, T] f32 — the residual ``flash_attention_bwd`` needs.
     """
+    b0, t0 = q.shape[:2]
+    p = rows_per_tile(b0, t0, k.shape[1], block_q)
+    if p > 1:
+        q, k, v = (_pack(x, p) for x in (q, k, v))
     b, t, h, d = q.shape
     s, kv = k.shape[1], k.shape[2]
     assert h % kv == 0, (h, kv)
@@ -149,9 +206,18 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kh = _heads_major(k, sp - s)
     vh = _heads_major(v, sp - s)
 
+    seg_len = t0 if p > 1 else None
+    if p > 1:
+        # at trace time; imported here, as the runtime package imports
+        # the models and so this module
+        from repro.runtime import telemetry as _tel
+        _tel.instant("kernels.flash_pack", cat="kernels",
+                     args={"t": t0, "rows_per_tile": p,
+                           "tile_fill": t / tp})
     kernel = functools.partial(
         _attn_kernel, scale=scale, block_q=block_q, block_k=block_k,
-        seq_k=s, causal=causal, window=window, save_lse=return_lse)
+        seq_k=s, causal=causal, window=window, seg_len=seg_len,
+        save_lse=return_lse)
     q_spec = pl.BlockSpec((None, None, block_q, d),
                           lambda bi, hi, qi, kj: (bi, hi, qi, 0))
     kv_spec = pl.BlockSpec((None, None, block_k, d),
@@ -174,9 +240,14 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         interpret=interpret,
     )(qh, kh, vh)
     out = jnp.swapaxes(got[0][:, :, :t], 1, 2)
-    if return_lse:
-        return out, got[1][:, :, :t, 0]
-    return out
+    if p > 1:
+        out = _unpack(out, b0, t0)
+    if not return_lse:
+        return out
+    lse = got[1][:, :, :t, 0]
+    if p > 1:
+        lse = jnp.swapaxes(_unpack(jnp.swapaxes(lse, 1, 2), b0, t0), 1, 2)
+    return out, lse
 
 
 # ---------------------------------------------------------------------------
@@ -196,7 +267,7 @@ def _replay(q, k, do, v, lse, dd, mask, scale):
 
 def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                         dq_ref, dq_scr, *, scale, block_q, block_k, seq_k,
-                        causal, window):
+                        causal, window, seg_len):
     """dq accumulated over kv blocks (last grid axis sequential):
     dq += ds·K·scale."""
     qi = pl.program_id(2)
@@ -208,7 +279,7 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     k = k_ref[...].astype(jnp.float32)
-    mask = _mask(qi, kj, block_q, block_k, seq_k, causal, window)
+    mask = _mask(qi, kj, block_q, block_k, seq_k, causal, window, seg_len)
     _, ds = _replay(q_ref[...].astype(jnp.float32), k,
                     do_ref[...].astype(jnp.float32),
                     v_ref[...].astype(jnp.float32), lse_ref[...],
@@ -222,7 +293,7 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
 def _attn_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dd_ref,
                          dk_ref, dv_ref, dk_scr, dv_scr, *, scale, block_q,
-                         block_k, seq_k, causal, window):
+                         block_k, seq_k, causal, window, seg_len):
     """dk/dv for one k-block accumulated over q blocks (last grid axis):
     dv += pᵀ·dO; dk += dsᵀ·Q·scale. Emitted per q-head; the wrapper sums
     heads over each GQA group."""
@@ -237,7 +308,7 @@ def _attn_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dd_ref,
 
     q = q_ref[...].astype(jnp.float32)
     do = do_ref[...].astype(jnp.float32)
-    mask = _mask(qj, ki, block_q, block_k, seq_k, causal, window)
+    mask = _mask(qj, ki, block_q, block_k, seq_k, causal, window, seg_len)
     p, ds = _replay(q, k_ref[...].astype(jnp.float32), do,
                     v_ref[...].astype(jnp.float32), lse_ref[...],
                     dd_ref[...], mask, scale)
@@ -272,12 +343,20 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal: bool = True,
     ``flash_attention(..., return_lse=True)``. Recompute-free: the online
     softmax is replayed as ``p = exp(s − LSE)`` — one pass per kernel.
     """
+    # D = rowsum(dO ∘ O): tiny elementwise reduce, cheaper outside
+    dd = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+    b0, t0 = q.shape[:2]
+    p = rows_per_tile(b0, t0, k.shape[1], block_q)
+    if p > 1:
+        # the forward's packing; the batch's padding rows, like the
+        # tile's, carry dO = 0, D = 0 and the LSE sentinel
+        q, k, v, do, dd = (_pack(x, p) for x in (q, k, v, do, dd))
+        lse = jnp.swapaxes(_pack(jnp.swapaxes(lse, 1, 2), p, _LSE_PAD),
+                           1, 2)
     b, t, h, d = q.shape
     s, kv = k.shape[1], k.shape[2]
     group = h // kv
     scale = d ** -0.5
-    # D = rowsum(dO ∘ O): tiny elementwise reduce, cheaper outside
-    dd = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
 
     tp = math.ceil(t / block_q) * block_q
     sp = math.ceil(s / block_k) * block_k
@@ -301,7 +380,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal: bool = True,
             (lambda bi, hi, i, j: (bi, hi // group, i, 0)))
 
     kernel_kw = dict(scale=scale, block_q=block_q, block_k=block_k,
-                     seq_k=s, causal=causal, window=window)
+                     seq_k=s, causal=causal, window=window,
+                     seg_len=t0 if p > 1 else None)
     dq = pl.pallas_call(
         functools.partial(_attn_bwd_dq_kernel, **kernel_kw),
         grid=(b, h, tp // block_q, sp // block_k),
@@ -333,5 +413,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal: bool = True,
         return jnp.swapaxes(x, 1, 2)
 
     dq = jnp.swapaxes(dq[:, :, :t], 1, 2)
-    return (dq.astype(q.dtype), fold(dkh).astype(k.dtype),
-            fold(dvh).astype(v.dtype))
+    dk, dv = fold(dkh), fold(dvh)
+    if p > 1:
+        dq, dk, dv = (_unpack(x, b0, t0) for x in (dq, dk, dv))
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)

@@ -4,6 +4,7 @@ values AND gradients, across dense/GQA shapes and ragged
 ``N % block_n != 0`` edges. Also covers mode resolution and the
 fused-vs-reference trainer path (loss + parameter grads)."""
 import dataclasses
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -359,6 +360,79 @@ def test_dense_attention_dispatch_parity(t, h, kv, window):
         with dispatch.forced(mode):
             out = dispatch.dense_attention(q, k, v, window=window)
         _close(out, exp, rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# short rows packed several to a flash tile under a block-diagonal mask
+# ---------------------------------------------------------------------------
+
+def _pallas_dense_grads(q, k, v, window):
+    def loss(q_, k_, v_):
+        with dispatch.forced("pallas"):
+            out = dispatch.dense_attention(q_, k_, v_, window=window)
+        return jnp.sum(out * out), out
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    return (out, *grads)
+
+
+@pytest.mark.parametrize("b,t,h,kv,window,rows", [
+    (12, 20, 16, 8, None, 6),    # the train cells' T, GQA 16/8
+    (7, 20, 4, 4, 8, 6),         # batch not a multiple of the rows a tile
+    (2, 20, 16, 8, 8, 2),        # batch under the rows a tile
+    (9, 13, 4, 4, None, 9),      # the serve prefill's T, MHA
+    (2, 13, 4, 2, 8, 2),
+    (2, 100, 4, 2, None, 1),     # over half a tile: unpacked, bit for bit
+])
+def test_flash_packed_rows_parity(monkeypatch, b, t, h, kv, window, rows):
+    """Forward and (dq, dk, dv) of the flash kernels on packed rows match
+    the dense reference and its gradient; where one row fills a tile the
+    wrapper traces the unpacked route op for op."""
+    fa = importlib.import_module("repro.kernels.flash_attention")
+    assert fa.rows_per_tile(b, t, t, 128) == rows
+    d = 64
+    q = jnp.asarray(RNG.standard_normal((b, t, h, d)), jnp.float32)
+    k = jnp.asarray(RNG.standard_normal((b, t, kv, d)), jnp.float32)
+    v = jnp.asarray(RNG.standard_normal((b, t, kv, d)), jnp.float32)
+    got = _pallas_dense_grads(q, k, v, window)
+
+    def ref_loss(q_, k_, v_):
+        out = ref.reference_attention(q_, k_, v_, window=window)
+        return jnp.sum(out * out), out
+    (_, out), grads = jax.value_and_grad(ref_loss, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    _close(got[0], out, rtol=2e-5, atol=2e-5)
+    for a, e in zip(got[1:], grads):
+        _close(a, e, rtol=2e-4, atol=2e-4)
+    if rows == 1:
+        packed = jax.make_jaxpr(_pallas_dense_grads, static_argnums=3)(
+            q, k, v, window)
+        monkeypatch.setattr(fa, "rows_per_tile", lambda *_: 1)
+        unpacked = _pallas_dense_grads(q, k, v, window)
+        assert str(jax.make_jaxpr(_pallas_dense_grads, static_argnums=3)(
+            q, k, v, window)) == str(packed)
+        for a, e in zip(got, unpacked):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(e))
+
+
+@pytest.mark.parametrize("t,rows", [(20, 6), (100, None)])
+def test_flash_pack_event(monkeypatch, t, rows):
+    """Under ``REPRO_TRACE`` the forward records ``kernels.flash_pack``
+    when it packs rows, with the rows a tile and their fill; one row a
+    tile records nothing."""
+    from repro.runtime import telemetry
+    monkeypatch.setattr(telemetry, "RECORDING", True)
+    telemetry.reset()
+    q = jnp.zeros((6, t, 2, 64), jnp.float32)
+    with dispatch.forced("pallas"):
+        jax.eval_shape(dispatch.dense_attention, q, q, q)
+    got = [e["args"] for e in telemetry.drain()
+           if e["name"] == "kernels.flash_pack"]
+    if rows is None:
+        assert got == []
+    else:
+        assert got == [{"t": t, "rows_per_tile": rows,
+                        "tile_fill": rows * t / 128}]
 
 
 def test_attention_decode_routes_through_dispatch():

@@ -77,6 +77,32 @@ def test_flash_attention_grad(shape):
     _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q)
 
 
+@pytest.mark.parametrize("b,t,h,kv", [
+    (144, 20, 16, 8),     # internlm2-1.8b train: GQA 16/8, 9 rows x 16
+    (144, 20, 32, 32),    # deepseek-7b train: MHA
+    (32, 13, 32, 32),     # deepseek-7b serve prefill, batch 32
+])
+def test_dense_attention_packed_rows(shape, monkeypatch, b, t, h, kv):
+    """The benchmark cells' short-row attention: forward and gradients of
+    ``dispatch.dense_attention`` take the flash kernels with rows packed
+    several to a tile, so no f32 tile per (row, head) is made around
+    them, as the lane-replicated LSE and D of unpacked rows were."""
+    monkeypatch.setattr(dispatch, "interpret_mode", lambda: False)
+
+    def fwd(q, k, v):
+        with dispatch.forced("pallas"):
+            return dispatch.dense_attention(q, k, v)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+    args = (shape((b, t, h, D)), shape((b, t, kv, D)), shape((b, t, kv, D)))
+    _compile(fwd, *args)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    *args).as_text()
+    assert text.count("tpu_custom_call") >= 3     # fwd, dq, dk/dv
+    assert f"f32[{b},{h},128,128]" not in text
+
+
 def test_decode_attention(shape):
     _compile(lambda q, k, v, b: decode_attention(q, k, v, b),
              shape((8, 1, H, D)), shape((8, 300, H, D)),
